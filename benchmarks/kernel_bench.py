@@ -22,9 +22,11 @@ import os
 import platform
 import time
 
-# the serving bench's tp_serving sweep (driven from this process) needs 8
-# virtual CPU devices; must be set before jax initializes the backend
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+# The serving bench's tp_serving sweep (driven from this process) needs 8
+# virtual CPU devices. The CPU entry points set this before jax
+# initializes its backend; importing the module leaves XLA_FLAGS alone, so
+# nothing that runs on the chip inherits it.
+VIRTUAL_CPU_DEVICES = "--xla_force_host_platform_device_count=8"
 
 import jax
 import jax.numpy as jnp
@@ -368,6 +370,7 @@ def run(json_path: str | None = None, smoke: bool = False) -> list[tuple[str, fl
 
 
 if __name__ == "__main__":
+    os.environ.setdefault("XLA_FLAGS", VIRTUAL_CPU_DEVICES)
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="seconds-scale subset (CI artifact mode)")

@@ -9,6 +9,7 @@ microseconds as the name indicates).
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 
 
@@ -24,6 +25,8 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import cycles, kernel_bench, throughput_model
+
+    os.environ.setdefault("XLA_FLAGS", kernel_bench.VIRTUAL_CPU_DEVICES)
 
     sections = [
         ("paper tables II/III/IV + fig6", throughput_model.run),

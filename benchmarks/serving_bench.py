@@ -75,9 +75,10 @@ import dataclasses
 import os
 import time
 
-# the tp_serving sweep needs 8 virtual CPU devices; no-op when driven
-# from kernel_bench.py (which sets this before jax initializes)
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+# The tp_serving sweep needs 8 virtual CPU devices: the CPU entry points
+# (this script's main, kernel_bench.py, run.py) set XLA_FLAGS before jax
+# initializes; importing the module leaves it alone.
+VIRTUAL_CPU_DEVICES = "--xla_force_host_platform_device_count=8"
 
 import jax
 import jax.numpy as jnp
@@ -535,7 +536,7 @@ def tp_serving_sweep(cfg, params, smoke: bool = False) -> dict:
             "skipped": (
                 f"needs 4 devices for model_parallel=4, found {n_dev} — "
                 "set XLA_FLAGS=--xla_force_host_platform_device_count=8 "
-                "(kernel_bench.py sets it by default when unset)"
+                "(the CPU bench entry points set it by default when unset)"
             ),
         }
     tcfg = dataclasses.replace(cfg, n_kv_heads=4)
@@ -1045,6 +1046,7 @@ def serving_bench(json_path: str | None = None, smoke: bool = False):
 
 
 if __name__ == "__main__":
+    os.environ.setdefault("XLA_FLAGS", VIRTUAL_CPU_DEVICES)
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--json", default=None)

@@ -5,20 +5,20 @@ peak by letting an *analytic performance model* prune the configuration
 space so only a handful of candidates are ever instantiated. This module
 is the software analogue for our plan layer:
 
-1. **Hardware table + calibration.** The TPU-v5e roofline constants that
-   used to be hard-coded in ``launch/roofline.py`` become one entry in a
-   backend-keyed :data:`HARDWARE_TABLE` with a conservative CPU entry
-   covering the ``jnp`` / ``interpret`` fallbacks. When a measured
-   ``BENCH_kernel.json`` is available, :func:`calibrate_from_bench` fits
-   the peak-compute and memory-bandwidth terms to the *observed* envelope
-   of the packed/fused kernel sections (best plane-pass FLOP/s and HBM
+1. **Hardware table + calibration.** Roofline constants live in one
+   :data:`HARDWARE_TABLE` keyed by the ``device_kind`` jax reports, each
+   row with its source; a device missing from the table is an error, not
+   a default. When a measured ``BENCH_kernel.json`` from the running
+   backend is available, :func:`calibrate_from_bench` fits the
+   peak-compute and memory-bandwidth terms to the *observed* envelope of
+   the packed/fused kernel sections (best plane-pass FLOP/s and HBM
    byte/s across all measured configs), so the pruning model ranks
    candidates by this host's actual roofline, not a datasheet's.
 
 2. **Legality-first candidate generation.** :func:`tile_candidates`
    enumerates (bm, bn, bk) triples that Mosaic will actually accept —
    int8 tiles floored at bm >= 32, bn/bk multiples of the 128-wide lane,
-   bk a whole number of packed words, working set within the VMEM budget
+   bk a whole sublane tile of packed words, working set within the VMEM budget
    (``ops.tiles_legal`` is the shared predicate) — scores them with the
    calibrated roofline (padding waste + per-grid-step overhead are what
    separate candidates on a fixed problem), and returns at most
@@ -56,6 +56,7 @@ __all__ = [
     "HardwareModel",
     "MAX_CANDIDATES",
     "PlanAutotuner",
+    "TPU_V5E",
     "calibrate_from_bench",
     "hardware_model",
     "host_fingerprint",
@@ -90,7 +91,7 @@ GRID_STEP_OVERHEAD_S = 2e-6
 
 @dataclasses.dataclass(frozen=True)
 class HardwareModel:
-    """One row of the backend-keyed roofline table (all rates per chip)."""
+    """One row of the device-keyed roofline table (all rates per chip)."""
 
     name: str
     peak_flops_bf16: float  # dense MXU/FMA rate, FLOP/s
@@ -98,26 +99,27 @@ class HardwareModel:
     hbm_bw: float           # main-memory bandwidth, bytes/s
     link_bw: float          # per-link interconnect bandwidth, bytes/s
     hbm_bytes: int          # main-memory capacity
-    source: str = "builtin"  # "builtin" | "calibrated:<where>"
+    source: str = ""  # where the figures come from ("calibrated:<where>" once fitted)
 
     def compute_rate(self, int8: bool = True) -> float:
         return self.peak_flops_int8 if int8 else self.peak_flops_bf16
 
 
+#: ``device_kind`` of a TPU v5e chip, as jax reports it.
+TPU_V5E = "TPU v5 lite"
+
 HARDWARE_TABLE = {
-    # TPU v5e datasheet numbers — the constants launch/roofline.py used to
-    # hard-code, now one entry among peers.
-    "tpu": HardwareModel(
+    TPU_V5E: HardwareModel(
         name="tpu-v5e",
         peak_flops_bf16=197e12,
-        peak_flops_int8=394e12,
+        peak_flops_int8=393e12,
         hbm_bw=819e9,
-        link_bw=50e9,
+        link_bw=50e9,  # 1,600 Gbit/s of interconnect over 4 links
         hbm_bytes=16 * 1024**3,
+        source='Google Cloud documentation, "TPU v5e"',
     ),
-    # Conservative single-host CPU entry for the jnp / interpret
-    # fallbacks. Deliberately round numbers: calibrate_from_bench replaces
-    # them with the measured envelope whenever a bench report exists.
+    # The host CPU runs only the jnp / interpret routes, where tiles are
+    # inert or emulated: round numbers that rank candidates, nothing more.
     "cpu": HardwareModel(
         name="cpu-host",
         peak_flops_bf16=2e11,
@@ -125,25 +127,29 @@ HARDWARE_TABLE = {
         hbm_bw=2e10,
         link_bw=1e10,
         hbm_bytes=8 * 1024**3,
+        source="round ranking constants for the CPU interpreter; not measured",
     ),
 }
 
 
-def hardware_model(backend: str = "auto") -> HardwareModel:
-    """Resolve a backend name to its hardware-table row.
+def hardware_model(kind: Optional[str] = None) -> HardwareModel:
+    """The hardware-table row of ``kind`` (default: the running device).
 
-    ``pallas`` means a real TPU; ``jnp``/``interpret`` run on the host
-    CPU; ``auto`` asks jax which one this process actually has.
+    A device missing from :data:`HARDWARE_TABLE` raises: a roofline built
+    from another chip's peaks would rank tiles, and report shares, against
+    the wrong machine.
     """
-    if backend == "auto":
-        try:  # pragma: no cover - depends on host accelerators
-            import jax
+    if kind is None:
+        import jax
 
-            backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
-        except Exception:  # pragma: no cover - jax always importable here
-            backend = "jnp"
-    key = "tpu" if backend == "pallas" else "cpu"
-    return HARDWARE_TABLE[key]
+        kind = jax.devices()[0].device_kind
+    try:
+        return HARDWARE_TABLE[kind]
+    except KeyError:
+        raise ValueError(
+            f"device_kind {kind!r} is not in HARDWARE_TABLE (known: "
+            f"{sorted(HARDWARE_TABLE)}); add its published peaks with a source"
+        ) from None
 
 
 def _measured_rates(configs, shape_key, flops_of, bytes_of, wall_keys):
@@ -167,18 +173,24 @@ def _measured_rates(configs, shape_key, flops_of, bytes_of, wall_keys):
     return best_flops, best_bytes
 
 
-def calibrate_from_bench(bench, backend: str = "auto") -> HardwareModel:
+def calibrate_from_bench(bench, kind: Optional[str] = None) -> HardwareModel:
     """Fit the roofline terms to a measured ``BENCH_kernel.json``.
 
-    ``bench`` is the parsed report dict or a path to it. The fit is the
-    *envelope*: the fastest plane-pass FLOP/s and HBM byte/s observed
-    across the ``packed_plane_matmul`` and ``fused_linear*`` sections
-    become the peak-compute and bandwidth terms (a roofline is an upper
-    bound, so the best measurement is the tightest honest estimate).
-    Falls back to the builtin table row when the report is missing,
+    ``bench`` is the parsed report dict or a path to it; ``kind`` the
+    ``device_kind`` whose table row is the base (default: the running
+    device). The fit is the *envelope*: the fastest plane-pass FLOP/s and
+    HBM byte/s observed across the ``packed_plane_matmul`` and
+    ``fused_linear*`` sections become the peak-compute and bandwidth terms
+    (a roofline is an upper bound, so the best measurement is the tightest
+    honest estimate). Returns the table row when the report is missing,
     malformed, or has no usable kernel sections.
+
+    A report measured on another backend than the running one (its
+    ``jax_backend``) raises: interpret-mode CPU walls are not TPU peaks.
     """
-    base = hardware_model(backend)
+    import jax
+
+    base = hardware_model(kind)
     if isinstance(bench, str):
         try:
             with open(bench) as fh:
@@ -187,6 +199,13 @@ def calibrate_from_bench(bench, backend: str = "auto") -> HardwareModel:
             return base
     if not isinstance(bench, dict):
         return base
+    measured_on = bench.get("jax_backend")
+    if measured_on != jax.default_backend():
+        raise ValueError(
+            f"bench report was measured on backend {measured_on!r}, this "
+            f"process runs {jax.default_backend()!r}: its walls cannot "
+            "calibrate this device's roofline"
+        )
     benches = bench.get("benches", {})
     where = bench.get("host", "bench")
 
@@ -368,7 +387,7 @@ def tile_candidates(key, kernel: str, hw: Optional[HardwareModel] = None) -> lis
     heur = _heuristic_tiles(key, kernel)
     if kernel in JNP_KERNELS or key.backend == "jnp":
         return [heur]
-    hw = hw or hardware_model(key.backend)
+    hw = hw or hardware_model()
     int8 = kernel in INT8_TILE_KERNELS
     m, k, n = key.m, key.k, key.n
     passes = max(1, key.a_bits) * max(1, key.w_bits)
@@ -391,6 +410,7 @@ def tile_candidates(key, kernel: str, hw: Optional[HardwareModel] = None) -> lis
                     bk,
                     int8=int8,
                     vmem_bytes=_vmem_bytes(kernel, bm, bn, bk, key.a_bits, key.w_bits),
+                    k=k,
                 ):
                     continue
                 scored.append(
@@ -469,7 +489,7 @@ class PlanAutotuner:
         if record is not None:
             tiles = _record_tiles(record)
             if tiles is not None and ops.tiles_legal(
-                *tiles, int8=kernel in INT8_TILE_KERNELS
+                *tiles, int8=kernel in INT8_TILE_KERNELS, k=key.k
             ):
                 self.store_hits += 1
                 self._memo[key_id] = tiles

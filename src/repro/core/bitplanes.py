@@ -423,11 +423,22 @@ def pack_planes(
             raise ValueError(f"block must be a multiple of {WORD_BITS}, got {block}")
         # The clamp for small K rounds to the TPU lane width (128): the
         # fused kernel uses the pack block as its K tile, and a last-dim
-        # tile that is not a lane multiple would not lower on Mosaic.
-        # (An explicitly sub-lane caller-chosen block is left alone.)
+        # tile that is not a lane multiple would not lower on Mosaic. A
+        # clamped block spans all of K, so its (block / 32)-row word tile
+        # is the full extent, which Mosaic accepts at any row count; a
+        # block that splits K must be a multiple of 256 (8 word rows, the
+        # sublane tile) — ops.tiles_legal is the same rule. (An explicitly
+        # sub-lane caller-chosen block is left alone: the interpreter
+        # tests use them.)
         lane = 4 * WORD_BITS
         if block > lane:
             block = min(block, -(-k // lane) * lane)
+            if block < k and (block // WORD_BITS) % 8:
+                raise ValueError(
+                    f"block {block} splits K={k} into {block // WORD_BITS}-row "
+                    "word tiles; a K-splitting block must be a multiple of "
+                    f"{8 * WORD_BITS} (8 word rows) to lower on the TPU"
+                )
 
         def towords(x):
             return _to_words_blocked(x, axis, block)
@@ -652,8 +663,9 @@ def make_weight_planes(
     """Decompose (and, at bit-plane level, pack) a quantized weight matrix.
 
     ``w_q``: integer ``(K, N)`` weight. Stacked/scanned weights (leading
-    layer or expert dims) are handled by the caller via ``jax.vmap`` so the
-    stacked leaves keep their leading axes scannable.
+    layer or expert dims) are handled by the caller (``lax.map`` in
+    ``models.quant``) so the stacked leaves keep their leading axes
+    scannable.
 
     ``store`` (bit-plane level): ``"packed"`` keeps only the packed words
     (the HBM-lean serving format); ``"both"`` additionally keeps the raw

@@ -220,12 +220,13 @@ def truncation_audit(registry: Optional[PlanRegistry] = None) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _resolve_packed(packed: Optional[bool], backend: str, level: str) -> bool:
-    if level != "bitplane":
-        return False
-    if packed is None:
-        return backend == "pallas"
-    return bool(packed)
+def _resolve_packed(packed: Optional[bool], level: str) -> bool:
+    """Whether the packed plane kernel serves: only when asked for. Its
+    activation word block ``(bm, bk / 32)`` puts a sub-lane last dimension
+    before Mosaic whenever the K tile splits K, which the TPU compiler
+    refuses — so auto routing keeps to the fused and unpacked-plane
+    kernels, on the chip exactly as in the interpreter."""
+    return level == "bitplane" and bool(packed)
 
 
 def _build_plan(key: PlanKey, registry: "PlanRegistry") -> "MatmulPlan":
@@ -237,7 +238,7 @@ def _build_plan(key: PlanKey, registry: "PlanRegistry") -> "MatmulPlan":
     kernel_ok = (
         key.level == "bitplane" or (key.level == "digit" and key.variant == "booth")
     ) and int32_acc
-    use_packed = serial and int32_acc and _resolve_packed(key.packed, key.backend, key.level)
+    use_packed = serial and int32_acc and _resolve_packed(key.packed, key.level)
     if key.packed and not use_packed:
         raise ValueError(
             "packed=True requires level='bitplane', mode='fully_serial' and "
@@ -867,7 +868,7 @@ def make_plan(
     Activations are assumed quantized at the *effective* width by the
     caller (they are re-quantized per token anyway). ``shard`` is the
     static tensor-parallel placement triple (with local ``shapes``) — see
-    :class:`PlanKey`.
+    :class:`PlanKey`. ``backend="auto"`` defers to ``policy.backend``.
     """
     configured = policy.lookup(layer_name)
     if not configured.active:
@@ -884,7 +885,7 @@ def make_plan(
         variant=policy.variant,
         level=policy.level,
         mode=policy.mode,
-        backend=backend,
+        backend=policy.backend if backend == "auto" else backend,
         accum_dtype=accum_dtype,
         has_epilogue=has_epilogue,
         w_planes=w_planes,

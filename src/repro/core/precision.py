@@ -86,8 +86,17 @@ class PrecisionPolicy:
     #: the step. Requires the bitplane level (the checksums live in the
     #: packed plane cache).
     integrity: str = "off"
+    #: Where the policy's matmuls run (:mod:`repro.kernels.ops`):
+    #: ``"auto"`` = the Pallas kernels on a TPU, jnp elsewhere; ``"jnp"``
+    #: is the kernel-free reference route a chip run is checked against.
+    backend: str = "auto"
 
     def __post_init__(self):
+        if self.backend not in ("auto", "pallas", "interpret", "jnp"):
+            raise ValueError(
+                "backend must be 'auto', 'pallas', 'interpret' or 'jnp', "
+                f"got {self.backend!r}"
+            )
         if self.runtime_bits is not None:
             for b in self.runtime_bits:
                 if b is not None and not 1 <= b <= MAX_BITS:

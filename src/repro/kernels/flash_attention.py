@@ -29,6 +29,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+)
 
 NEG_INF = -1e30
 
@@ -181,8 +186,6 @@ def flash_attention(
                 f"{name} must be ({b},{hkv},{sk}), got {scale.shape}"
             )
 
-    import jax.experimental.pallas.tpu as pltpu  # CPU-safe (interpret mode)
-
     kv_steps = sk // block_k
     static_kv_len = sk if kv_len is None else kv_len
     if not 0 < static_kv_len <= sk:
@@ -238,20 +241,12 @@ def flash_attention(
             _scratch(block_q, 1),
             _scratch(block_q, d),
         ],
-        compiler_params=dict(
-            mosaic=dict(
-                dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-            )
-        )
-        if not interpret
-        else None,
+        compiler_params=None if interpret else _COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
 
 
 def _scratch(rows: int, cols: int):
-    import jax.experimental.pallas.tpu as pltpu  # deferred: CPU-safe import
-
     return pltpu.VMEM((rows, cols), jnp.float32)
 
 
@@ -384,8 +379,6 @@ def paged_flash_attention(
             raise ValueError(f"{name} must be {shape}, got {pool.shape}")
     pages = block_tables.shape[1]
 
-    import jax.experimental.pallas.tpu as pltpu  # CPU-safe (interpret mode)
-
     kernel = functools.partial(
         _paged_kernel,
         sm_scale=sm_scale,
@@ -441,15 +434,7 @@ def paged_flash_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, out_dtype),
-        compiler_params=dict(
-            mosaic=dict(
-                dimension_semantics=(
-                    "parallel", "parallel", "parallel", "arbitrary"
-                )
-            )
-        )
-        if not interpret
-        else None,
+        compiler_params=None if interpret else _COMPILER_PARAMS,
         interpret=interpret,
     )(
         block_tables.astype(jnp.int32),

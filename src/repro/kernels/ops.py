@@ -86,33 +86,46 @@ def _int8_bm(bm: int) -> int:
 
 #: Mosaic geometry shared by the legality predicate and the autotuner's
 #: candidate generator (core/autotune): the 128-wide vector lane, the
-#: (32, 128) minimum int8 tile, bits per packed plane word, and the
-#: per-core VMEM working-set budget (~16 MB on current TPUs; we cap the
-#: per-grid-step estimate at half to leave room for double buffering and
-#: semaphores).
+#: 8-row sublane, the (32, 128) minimum int8 tile, bits per packed plane
+#: word, and the per-core VMEM working-set budget (~16 MB on current
+#: TPUs; we cap the per-grid-step estimate at half to leave room for
+#: double buffering and semaphores).
 MOSAIC_LANE = 128
+MOSAIC_SUBLANE = 8
 MOSAIC_INT8_MIN_BM = 32
 PACKED_WORD_BITS = 32
 VMEM_BUDGET_BYTES = 8 * 2**20
 
 
 def tiles_legal(
-    bm: int, bn: int, bk: int, *, int8: bool = True, vmem_bytes: int = 0
+    bm: int,
+    bn: int,
+    bk: int,
+    *,
+    int8: bool = True,
+    vmem_bytes: int = 0,
+    k: int,
 ) -> bool:
     """Would Mosaic accept this (bm, bn, bk) tile triple?
 
     The single source of truth the autotuner's candidate generation and
     the property tests share: bm a positive sublane multiple of 8 (>= 32
     when the route keeps int8 operand tiles), bn and bk positive
-    multiples of the 128-wide lane (which also makes bk a whole number of
-    32-bit packed plane words), and — when the caller supplies its
+    multiples of the 128-wide lane, and — when the caller supplies its
     working-set estimate — the grid step within the VMEM budget.
+
+    The packed weight block is ``(P_w, bk / 32, bn)`` words, so its
+    ``bk / 32`` rows must be a whole sublane multiple: bk a multiple of
+    256, unless the block spans the whole packed extent (``bk >= k`` for
+    the contraction length ``k``).
     """
     if bm <= 0 or bn <= 0 or bk <= 0:
         return False
-    if bm % 8 or (int8 and bm < MOSAIC_INT8_MIN_BM):
+    if bm % MOSAIC_SUBLANE or (int8 and bm < MOSAIC_INT8_MIN_BM):
         return False
-    if bn % MOSAIC_LANE or bk % MOSAIC_LANE or bk % PACKED_WORD_BITS:
+    if bn % MOSAIC_LANE or bk % MOSAIC_LANE:
+        return False
+    if (bk // PACKED_WORD_BITS) % MOSAIC_SUBLANE and bk < k:
         return False
     return vmem_bytes <= VMEM_BUDGET_BYTES
 

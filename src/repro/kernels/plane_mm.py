@@ -28,6 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 
 def _plane_mm_kernel(pw_ref, a_ref, w_ref, o_ref, *, n_a: int, n_w: int, unroll: bool):
@@ -91,16 +96,13 @@ def plane_matmul(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((n_a * n_w,), lambda mi, ni, ki: (0,)),
+            # pair weights are read at a loop index: scalars from SMEM
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((n_a, bm, bk), lambda mi, ni, ki: (0, mi, ki)),
             pl.BlockSpec((n_w, bk, bn), lambda mi, ni, ki: (0, ki, ni)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        )
-        if not interpret
-        else None,
+        compiler_params=None if interpret else _COMPILER_PARAMS,
         interpret=interpret,
     )(pair_weights, a_planes, w_planes)

@@ -40,7 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitplanes import WORD_BITS, PackedPlanes, occupancy_per_tile
-from repro.kernels.plane_mm_packed import _expand_words, _pad_dim
+from repro.kernels.plane_mm_packed import _COMPILER_PARAMS, _expand_words, _pad_dim
 
 ACTIVATIONS = {
     "none": lambda x: x,
@@ -288,11 +288,7 @@ def fused_plane_linear(
             (mp, np_), jnp.dtype(out_dtype) if has_epilogue else jnp.int32
         ),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        )
-        if not interpret
-        else None,
+        compiler_params=None if interpret else _COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
     return out[:m, :n]

@@ -34,6 +34,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitplanes import WORD_BITS, PackedPlanes, occupancy_per_tile
 
+#: (M, N, K) grid: output tiles are independent, K accumulates in scratch
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
+
 
 def _expand_words(words: jax.Array, axis: int) -> jax.Array:
     """(.., W, ..) int32 words -> (.., 32*W, ..) {0,1} int32 along ``axis``."""
@@ -242,11 +247,7 @@ def plane_matmul_packed(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        )
-        if not interpret
-        else None,
+        compiler_params=None if interpret else _COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
     return out[:m, :n]

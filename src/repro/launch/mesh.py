@@ -18,12 +18,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: Mesh has no axis_types argument
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(shape, axes) -> Mesh:
@@ -35,8 +30,6 @@ def _mesh(shape, axes) -> Mesh:
             "(the dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512)"
         )
     arr = np.array(devices[:n]).reshape(shape)
-    if AxisType is None:
-        return Mesh(arr, axes)
     return Mesh(arr, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

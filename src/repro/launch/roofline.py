@@ -20,18 +20,18 @@ from typing import Dict, Optional
 
 from repro.core.autotune import (  # noqa: F401  (re-exported table)
     HARDWARE_TABLE,
+    TPU_V5E,
     HardwareModel,
     calibrate_from_bench,
     hardware_model,
 )
 
-# TPU v5e per-chip constants. These used to be hard-coded here; they are
-# now one entry in the backend-keyed HARDWARE_TABLE (core/autotune) with
-# a CPU/interpret fallback row and calibrate_from_bench() fitting the
-# terms to a measured BENCH_kernel.json. The module-level aliases stay
-# for existing callers (dryrun.py reads HBM_BYTES) and remain the
-# default when a Roofline is built without an explicit hardware model.
-_TPU = HARDWARE_TABLE["tpu"]
+# The dry-run's deployment chip is a TPU v5e: its row of the
+# device_kind-keyed HARDWARE_TABLE (core/autotune, with the figures'
+# source). The module-level aliases stay for existing callers (dryrun.py
+# reads HBM_BYTES) and remain the default when a Roofline is built without
+# an explicit hardware model.
+_TPU = hardware_model(TPU_V5E)
 PEAK_FLOPS_BF16 = _TPU.peak_flops_bf16  # FLOP/s
 PEAK_FLOPS_INT8 = _TPU.peak_flops_int8
 HBM_BW = _TPU.hbm_bw  # B/s

@@ -53,8 +53,9 @@ from repro.configs import ARCH_NAMES, get_config, get_reduced
 from repro.core import integrity
 from repro.core.precision import PrecisionPolicy
 from repro.launch import sampling
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import (
-    make_cb_decode_step, make_chunk_prefill_step, make_prefill_step,
+    jit_step, make_cb_decode_step, make_chunk_prefill_step, make_prefill_step,
     make_serve_step, make_tp_cb_decode_step, make_tp_chunk_prefill_step,
     make_tp_prefill_step,
 )
@@ -269,7 +270,9 @@ class _PlanTuning:
                     "only read/written by the attached tuner)"
                 )
             return
-        from repro.core.autotune import PlanAutotuner, calibrate_from_bench
+        from repro.core.autotune import (
+            PlanAutotuner, calibrate_from_bench, hardware_model,
+        )
         from repro.runtime.plan_store import PlanStore
 
         registry = plan_mod.DEFAULT_REGISTRY
@@ -284,10 +287,12 @@ class _PlanTuning:
             # tune-once applies across engine instances too.
             self.autotuner = current
             return
-        # Calibrate the pruning model against this host's measured bench
-        # report when one exists; the builtin table row otherwise.
-        bench_path = os.environ.get("BENCH_KERNEL_JSON", "BENCH_kernel.json")
-        self.autotuner = PlanAutotuner(store=store, hw=calibrate_from_bench(bench_path))
+        # Calibrate the pruning model against a bench report only when one
+        # is named ($BENCH_KERNEL_JSON; it must come from this backend);
+        # the device's table row otherwise.
+        bench_path = os.environ.get("BENCH_KERNEL_JSON")
+        hw = calibrate_from_bench(bench_path) if bench_path else hardware_model()
+        self.autotuner = PlanAutotuner(store=store, hw=hw)
         registry.attach_tuner(self.autotuner)
 
     def plan_stats(self) -> dict:
@@ -346,13 +351,13 @@ class Engine(_PrecisionDial, _IntegrityRuntime, _PlanTuning):
         pcol = integrity.Collector() if check else None
         scol = integrity.Collector() if check else None
         return (
-            jax.jit(
+            jit_step(
                 make_prefill_step(
                     self.cfg, self.policy, max_len=self.max_len,
                     precision=precision, collector=pcol,
                 )
             ),
-            jax.jit(
+            jit_step(
                 make_serve_step(
                     self.cfg, self.policy, sample_fn=self.sample_fn,
                     precision=precision, collector=scol,
@@ -698,14 +703,14 @@ class ContinuousBatchingEngine(_PrecisionDial, _IntegrityRuntime, _PlanTuning):
             # sharded buffers are committed to the mesh (re-layout on
             # donation would cost more than the copy it saves on CPU CI).
             return (
-                jax.jit(
+                jit_step(
                     make_tp_prefill_step(
                         self.cfg, self.tp, self._tp_specs, self.policy,
                         max_len=self.max_len, kv_quant=self.kv_quant,
                         precision=precision, collector=pcol,
                     )
                 ),
-                jax.jit(
+                jit_step(
                     make_tp_cb_decode_step(
                         self.cfg, self.tp, self._tp_specs, self.policy,
                         max_len=self.max_len, n_slots=self.n_slots,
@@ -718,14 +723,14 @@ class ContinuousBatchingEngine(_PrecisionDial, _IntegrityRuntime, _PlanTuning):
                 scol,
             )
         return (
-            jax.jit(
+            jit_step(
                 make_prefill_step(
                     self.cfg, self.policy, max_len=self.max_len,
                     kv_quant=self.kv_quant, precision=precision,
                     collector=pcol,
                 )
             ),
-            jax.jit(
+            jit_step(
                 make_cb_decode_step(
                     self.cfg, self.policy, precision=precision, collector=scol
                 ),
@@ -769,7 +774,7 @@ class ContinuousBatchingEngine(_PrecisionDial, _IntegrityRuntime, _PlanTuning):
                     self.cfg, self.policy, precision=precision,
                     with_logits=True,
                 )
-            self._shadow_compiled[precision] = jax.jit(step)
+            self._shadow_compiled[precision] = jit_step(step)
         return self._shadow_compiled[precision]
 
     def plane_cache_bytes_per_device(self) -> int:
@@ -867,7 +872,7 @@ class ContinuousBatchingEngine(_PrecisionDial, _IntegrityRuntime, _PlanTuning):
                 fn = make_chunk_prefill_step(
                     self.cfg, self.policy, precision=precision, collector=ccol,
                 )
-            self._chunk_compiled[precision] = (jax.jit(fn), ccol)
+            self._chunk_compiled[precision] = (jit_step(fn), ccol)
         return self._chunk_compiled[precision]
 
     def _chunk_checked(self, steps, scratch, toks, rid, integ):
@@ -1962,6 +1967,7 @@ def _print_plan_stats(engine) -> None:
 def main():
     args = build_parser().parse_args()
     validate_args(args)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if not cfg.is_decoder:

@@ -28,6 +28,22 @@ from repro.models.transformer import forward, loss_fn
 from repro.optim import OptimConfig, apply_updates, clip_by_global_norm
 from repro.optim import compress as gcomp
 
+#: Compiler options of the serving steps. By default XLA may keep a bf16
+#: intermediate at f32 inside a fusion ("excess precision"), so where a
+#: value rounds depends on how the program happens to be fused: a Pallas
+#: kernel writes its bf16 output to memory, while the same epilogue on the
+#: jnp route can stay f32 into its consumer. On a TPU v5e that alone moved
+#: 13% of the greedy argmaxes of a two-layer granite-3-8b forward. With it
+#: off every route rounds where the source says, so the Pallas and jnp
+#: routes — and a tensor-parallel engine and one chip — agree bit for bit.
+EXACT_ROUNDING = {"xla_allow_excess_precision": False}
+
+
+def jit_step(fn, **kw):
+    """``jax.jit`` under :data:`EXACT_ROUNDING`: how the serving engines
+    compile their prefill and decode steps."""
+    return jax.jit(fn, compiler_options=EXACT_ROUNDING, **kw)
+
 
 def make_train_step(
     cfg: ModelConfig,
@@ -269,16 +285,14 @@ def make_serve_step(
 
 
 def _tp_shard_map(fn, tp, in_specs, out_specs):
-    """``shard_map`` with the repo's compatibility/compile settings.
+    """``jax.shard_map`` with the repo's compile settings.
 
-    ``check_rep=False``: the bodies return replicated values by
+    ``check_vma=False``: the bodies return replicated values by
     construction (identical deterministic math per shard after psum/pmax),
-    but jax 0.4's replication checker cannot prove that through the
+    but the varying-manual-axes checker cannot prove that through the
     integer plane kernels."""
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        fn, mesh=tp.mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        fn, mesh=tp.mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 

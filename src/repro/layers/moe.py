@@ -39,21 +39,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma in a
-# different release than the top-level promotion: probe the signature.
-import inspect as _inspect
-
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
 from repro.core.quantize import fake_quant
 from repro.layers.linear import linear_init
 from repro.sharding.rules import current_rules
@@ -285,13 +270,13 @@ def moe_apply_gshard(
         seq_sharded=seq_ok,
     )
 
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(specs["x"], specs["router"], specs["gate"], specs["up"],
                   specs["down"]),
         out_specs=(x_spec, P()),
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )(x, params["router"]["w"], params["gate"], params["up"], params["down"])
     return out, aux
 

@@ -32,7 +32,11 @@ slices/recomputations of what this module would build on one device.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+
 import jax
+import jax.numpy as jnp
 
 from repro.core import bitplanes as bp
 from repro.core.plan import plan_cacheable
@@ -62,22 +66,83 @@ def decompose_linear_weight(
     match). Only the packed words and the per-channel scales ride in the
     serving tree.
 
-    Stacked/scanned weights (leading layer/expert dims) are vmapped so the
-    cache leaves keep their leading axes scannable by ``lax.scan``. A
+    Stacked/scanned weights (leading layer/expert dims) are decomposed
+    one matrix at a time (``lax.map`` inside one jitted program), so the
+    cache leaves keep their leading axes scannable by ``lax.scan`` while
+    the int32 plane temporaries stay one matrix deep: eagerly over a whole
+    stack they would outgrow a chip's memory at published widths. A
     module-level function so load-time decomposition counts can be
     observed (tests monkeypatch this).
     """
+    return _decompose(
+        w_q, w_bits=w_bits, variant=variant, level=level, block=block,
+        checksum=checksum,
+    )
 
-    def one(w):
+
+#: Output columns decomposed per step. Packing materializes ~45 bytes of
+#: temporaries per weight on a TPU: 9 GiB for a whole 4096 x 49408
+#: lm_head, under 1 GiB per 4096-column slice.
+COLUMN_CHUNK = 4096
+
+
+def _join_columns(parts: bp.WeightPlanes, n: int) -> bp.WeightPlanes:
+    """Reassemble per-column-chunk caches (leading chunk axis) into the
+    cache of the whole ``n``-column matrix: packing runs along K, so
+    columns are independent — words and planes concatenate, occupancy
+    ORs and checksums sum over the chunks (zero padding columns are
+    inert in both)."""
+
+    def cat(x):  # (chunks, ..., C) -> (..., chunks * C)[..., :n]
+        if x is None:
+            return None
+        x = jnp.moveaxis(x, 0, -2)
+        return x.reshape(x.shape[:-2] + (-1,))[..., :n]
+
+    packed = parts.packed
+    if packed is not None:
+        packed = dataclasses.replace(
+            packed,
+            mag=cat(packed.mag),
+            sign=cat(packed.sign),
+            occupancy=jnp.any(packed.occupancy != 0, axis=0).astype(jnp.int32),
+            checksum=None if packed.checksum is None else packed.checksum.sum(0),
+        )
+    return dataclasses.replace(parts, packed=packed, planes=cat(parts.planes))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("w_bits", "variant", "level", "block", "checksum")
+)
+def _decompose(w_q, *, w_bits, variant, level, block, checksum):
+    def whole(w):
         return bp.make_weight_planes(
             w, w_bits=w_bits, variant=variant, level=level, block=block,
             checksum=checksum,
         )
 
+    def one(w):  # (K, N), N split into COLUMN_CHUNK slices
+        k, n = w.shape
+        n_chunks = -(-n // COLUMN_CHUNK)
+        if n_chunks == 1:
+            return whole(w)
+        w = jnp.pad(w, ((0, 0), (0, n_chunks * COLUMN_CHUNK - n)))
+        chunks = jnp.moveaxis(w.reshape(k, n_chunks, COLUMN_CHUNK), 1, 0)
+        return _join_columns(jax.lax.map(whole, chunks), n)
+
     fn = one
     for _ in range(w_q.ndim - 2):
-        fn = jax.vmap(fn)
+        fn = functools.partial(jax.lax.map, fn)
     return fn(w_q)
+
+
+@functools.partial(jax.jit, static_argnames=("bits",))
+def _quantize_weight(w, bits: int):
+    """Per-output-channel quantization (reduce over the input dim, axis
+    -2, so stacked/scanned leading dims are handled) as one program: the
+    f32 copy of the weight and its temporaries never exist whole."""
+    q = quantize(w.astype(jnp.float32), bits, axis=-2)
+    return q.values, q.scale
 
 
 def quantize_params(
@@ -118,15 +183,11 @@ def quantize_params(
                         f"layer {path}: value_bits must be in [1, {prec.w_bits}] "
                         f"(the configured storage width), got {value_bits}"
                     )
-                # reduce over the input dim (axis -2; handles stacked/scanned
-                # leading dims) -> per-output-channel scales.
-                q = quantize(
-                    node["w"].astype("float32"), value_bits or prec.w_bits, axis=-2
-                )
-                out = {"w_q": q.values, "w_scale": q.scale}
+                w_q, w_scale = _quantize_weight(node["w"], value_bits or prec.w_bits)
+                out = {"w_q": w_q, "w_scale": w_scale}
                 if plane_cache and plan_cacheable(policy, prec):
                     out["w_planes"] = decompose_linear_weight(
-                        q.values,
+                        w_q,
                         w_bits=prec.w_bits,
                         variant=policy.variant,
                         level=policy.level,

@@ -270,9 +270,9 @@ def shard_quantized(
     from repro.core.plan import plan_cacheable
     from repro.models.quant import decompose_linear_weight, quantize_params
 
-    base = quantize_params(
-        params, policy, plane_cache=plane_cache, value_bits=value_bits
-    )
+    # The base tree only supplies global scales and integers: every plane
+    # cache is rebuilt per shard below, so the base never builds one.
+    base = quantize_params(params, policy, value_bits=value_bits)
     n = tp.size
     stacked_spec = tp.shard_spec()
 
@@ -294,7 +294,7 @@ def shard_quantized(
             else:  # col / vocab: per-output-channel scales slice with N
                 out["w_scale"] = _slice_stack(node["w_scale"], -1, n)
                 spec["w_scale"] = stacked_spec
-            if "w_planes" in node and plan_cacheable(policy, prec):
+            if plane_cache and plan_cacheable(policy, prec):
                 wp = decompose_linear_weight(
                     w_q,
                     w_bits=prec.w_bits,

@@ -93,9 +93,10 @@ OPS_SURFACE = {
     "bk: 'Optional[int]', n: 'Optional[int]' = None, "
     "bn: 'Optional[int]' = None) -> 'tuple[int, ...]'",
     # PR 10: the shared Mosaic-legality predicate the autotuner's
-    # candidate generator and the stored-record validator both gate on
+    # candidate generator and the stored-record validator both gate on;
+    # ``k`` lets a packed word block that spans all of K pass
     "tiles_legal": "(bm: 'int', bn: 'int', bk: 'int', *, "
-    "int8: 'bool' = True, vmem_bytes: 'int' = 0) -> 'bool'",
+    "int8: 'bool' = True, vmem_bytes: 'int' = 0, k: 'int') -> 'bool'",
     "Epilogue": "NamedTuple('a_scale', 'w_scale', 'bias', 'activation', "
     "'out_dtype')",
     "apply_epilogue": "(acc: 'jax.Array', ep: 'Epilogue') -> 'jax.Array'",

@@ -24,11 +24,15 @@ try:
 except ImportError:  # container image without hypothesis: seeded fallback
     from _hypothesis_compat import given, settings, strategies as st
 
+import jax.numpy as jnp
+
 from repro.core import autotune
+from repro.core import bitplanes as bp
 from repro.core import plan as plan_mod
 from repro.core.autotune import (
     HARDWARE_TABLE,
     MAX_CANDIDATES,
+    TPU_V5E,
     PlanAutotuner,
     calibrate_from_bench,
     hardware_model,
@@ -102,9 +106,12 @@ def test_candidates_always_legal(m, k, n, a_bits, w_bits, kernel):
     heur = autotune._heuristic_tiles(key, kernel)
     assert heur in cands, "auto_tiles answer must always be a candidate"
     for bm, bn, bk in cands:
-        assert ops.tiles_legal(bm, bn, bk, int8=int8), (kernel, bm, bn, bk)
+        assert ops.tiles_legal(bm, bn, bk, int8=int8, k=k), (kernel, bm, bn, bk)
         assert bm % 8 == 0 and bn % ops.MOSAIC_LANE == 0
         assert bk % ops.MOSAIC_LANE == 0 and bk % ops.PACKED_WORD_BITS == 0
+        # the (bk / 32)-row packed word tile is a sublane multiple, or
+        # spans the whole packed extent
+        assert (bk // ops.PACKED_WORD_BITS) % 8 == 0 or bk >= k
         if int8:
             assert bm >= ops.MOSAIC_INT8_MIN_BM
         vmem = autotune._vmem_bytes(kernel, bm, bn, bk, a_bits, w_bits)
@@ -131,30 +138,69 @@ def test_candidates_ranked_by_calibrated_model():
     """A bandwidth-starved model must not change legality, only order."""
     key = _key(m=512, k=1024, n=1024)
     slow = HARDWARE_TABLE["cpu"]
-    fast = HARDWARE_TABLE["tpu"]
+    fast = HARDWARE_TABLE[TPU_V5E]
     for hw in (slow, fast):
         cands = tile_candidates(key, "cached_packed", hw)
         assert len(cands) <= MAX_CANDIDATES
         for tiles in cands:
-            assert ops.tiles_legal(*tiles, int8=False)
+            assert ops.tiles_legal(*tiles, int8=False, k=key.k)
+
+
+def test_bk_splitting_k_must_fill_word_sublanes():
+    """bk=128 packs to a 4-row word tile: refused once it splits K (the
+    v5e compiler refuses it — see test_tpu_compile), accepted when it is
+    the whole extent."""
+    assert not ops.tiles_legal(32, 256, 128, k=4096)
+    assert ops.tiles_legal(32, 256, 256, k=4096)
+    assert ops.tiles_legal(32, 256, 128, k=128)
+    assert ops.tiles_legal(32, 256, 384, k=300)
+    # the pack block is the fused kernel's K tile: the same rule holds
+    w = jnp.zeros((1000, 128), jnp.int8)
+    with pytest.raises(ValueError, match="8 word rows"):
+        bp.make_weight_planes(w, w_bits=8, variant="booth", level="bitplane", block=384)
+    clamped = bp.make_weight_planes(
+        w[:300], w_bits=8, variant="booth", level="bitplane", block=512
+    )
+    assert clamped.packed.block == 384  # spans K=300: legal at 12 word rows
+
+
+def test_hardware_table_keyed_by_device_kind():
+    v5e = hardware_model(TPU_V5E)
+    assert (v5e.peak_flops_bf16, v5e.peak_flops_int8, v5e.hbm_bw) == (
+        197e12, 393e12, 819e9,
+    )
+    assert "TPU v5e" in v5e.source
+    assert hardware_model() == HARDWARE_TABLE["cpu"]  # this process: CPU
+    with pytest.raises(ValueError, match="not in HARDWARE_TABLE"):
+        hardware_model("TPU v9 imaginary")
 
 
 # -- calibration + identity --------------------------------------------------
 
 
 def test_calibration_falls_back_on_garbage(tmp_path):
-    base = hardware_model("jnp")
-    assert calibrate_from_bench(str(tmp_path / "missing.json"), "jnp") == base
+    base = hardware_model("cpu")
+    assert calibrate_from_bench(str(tmp_path / "missing.json"), "cpu") == base
     torn = tmp_path / "torn.json"
     torn.write_text('{"benches": {"packed_plane_mat')
-    assert calibrate_from_bench(str(torn), "jnp") == base
-    assert calibrate_from_bench({"benches": {}}, "jnp") == base
-    assert calibrate_from_bench(None, "jnp") == base
+    assert calibrate_from_bench(str(torn), "cpu") == base
+    empty = {"jax_backend": "cpu", "benches": {}}
+    assert calibrate_from_bench(empty, "cpu") == base
+    assert calibrate_from_bench(None, "cpu") == base
+
+
+def test_calibration_refuses_report_from_another_backend():
+    """Interpret-mode CPU walls must never overwrite a TPU row (and a TPU
+    report says nothing about this CPU): the backend must match."""
+    for backend in ("tpu", None):
+        with pytest.raises(ValueError, match="measured on backend"):
+            calibrate_from_bench({"jax_backend": backend, "benches": {}}, "cpu")
 
 
 def test_calibration_fits_envelope():
     bench = {
         "host": "unit",
+        "jax_backend": "cpu",
         "benches": {
             "packed_plane_matmul": {
                 "configs": [
@@ -168,13 +214,13 @@ def test_calibration_fits_envelope():
             }
         },
     }
-    hw = calibrate_from_bench(bench, "jnp")
+    hw = calibrate_from_bench(bench, "cpu")
     flops = 2 * 128 * 256 * 128 * 4
     assert hw.peak_flops_int8 == pytest.approx(flops / 100e-6)
     assert hw.hbm_bw == pytest.approx(40_000 / 100e-6)
     assert hw.source == "calibrated:unit"
     # Untouched terms keep the builtin values.
-    assert hw.link_bw == hardware_model("jnp").link_bw
+    assert hw.link_bw == hardware_model("cpu").link_bw
 
 
 def test_host_fingerprint_stable_and_hostname_free():

@@ -81,7 +81,8 @@ def test_collectives_in_scan_counted_per_iteration():
 
         W = jnp.zeros((64, 96), jnp.float32)
         X = jnp.ones((32, 64), jnp.float32)
-        mesh = jax.make_mesh((8,), ("model",))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
         with mesh:
             def f(x, w):
                 def body(cr, _):

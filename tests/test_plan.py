@@ -8,6 +8,7 @@ jnp and interpret backends — no re-quantization, only a plane-prefix
 slice of the stored words.
 """
 
+import dataclasses
 import warnings
 
 import jax
@@ -392,3 +393,20 @@ def test_booth_closed_range_decomposition():
         dec = bp.to_bitplanes(x, bits, "booth")
         np.testing.assert_array_equal(dec.reconstruct(), x)
         assert int(jnp.max(jnp.abs(dec.planes))) <= 1
+
+
+def test_policy_backend_routes_its_plans():
+    """``PrecisionPolicy.backend`` picks where a policy's matmuls run —
+    how a chip run serves its jnp reference next to the Pallas route —
+    and plans key on it; an explicit ``make_plan`` backend still wins."""
+    pol = PrecisionPolicy.uniform(8, 8, variant="booth", level="bitplane")
+    shapes = ((8, 128), (128, 128))
+    auto = plan_mod.make_plan(pol, "layers/mlp/up", shapes)
+    assert auto.key.backend == ops.resolve_backend("auto")
+    ref = dataclasses.replace(pol, backend="jnp")
+    plan = plan_mod.make_plan(ref, "layers/mlp/up", shapes)
+    assert (plan.key.backend, plan.kernel) == ("jnp", "oracle")
+    explicit = plan_mod.make_plan(ref, "layers/mlp/up", shapes, "interpret")
+    assert explicit.key.backend == "interpret"
+    with pytest.raises(ValueError, match="backend must be"):
+        dataclasses.replace(pol, backend="tpu")

@@ -95,3 +95,32 @@ def test_policy_off_and_describe():
     pol = PrecisionPolicy.off()
     assert not pol.lookup("anything").active
     assert "PrecisionPolicy" in pol.describe()
+
+
+@pytest.mark.parametrize(
+    "shape,variant,checksum",
+    [((2, 300, 700), "booth", True), ((130, 513), "sbmwc", False)],
+)
+def test_column_chunked_decomposition_matches_whole(monkeypatch, shape, variant, checksum):
+    """Load-time decomposition splits N into COLUMN_CHUNK slices (so the
+    plane temporaries of a published-width lm_head fit a chip): the
+    reassembled cache — words, occupancy, checksums — equals the cache of
+    the whole matrix."""
+    from repro.models import quant
+
+    rng = np.random.default_rng(0)
+    w = jnp.asarray(rng.integers(-128, 128, shape), jnp.int8)
+    kw = dict(w_bits=8, variant=variant, level="bitplane", checksum=checksum)
+    whole = quant.decompose_linear_weight(w, **kw)
+    monkeypatch.setattr(quant, "COLUMN_CHUNK", 128)
+    quant._decompose.clear_cache()
+    try:
+        chunked = quant.decompose_linear_weight(w, **kw)
+    finally:
+        quant._decompose.clear_cache()
+    got, got_def = jax.tree_util.tree_flatten(chunked)
+    want, want_def = jax.tree_util.tree_flatten(whole)
+    assert got_def == want_def
+    for g, x in zip(got, want):
+        assert (g.shape, g.dtype) == (x.shape, x.dtype)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(x))

@@ -20,7 +20,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_reduced
@@ -172,9 +171,9 @@ def test_row_parallel_epilogue(setup):
                 policy=policy, bias=bb, activation="silu",
             )
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=tp.mesh, in_specs=(specs, P(), P()), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(tree, x, bias)
     np.testing.assert_array_equal(
         np.asarray(ref, np.float32), np.asarray(out, np.float32)
