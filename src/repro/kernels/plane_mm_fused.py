@@ -281,6 +281,7 @@ def fused_plane_linear(
     )
     out = pl.pallas_call(
         kernel,
+        name="fused_plane_linear",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),

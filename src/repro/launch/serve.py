@@ -48,6 +48,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import ARCH_NAMES, get_config, get_reduced
 from repro.core import integrity
@@ -1212,232 +1213,246 @@ class ContinuousBatchingEngine(_PrecisionDial, _IntegrityRuntime, _PlanTuning):
         t0 = time.time()
         while not sched.done or job is not None:
             t_iter = time.time()
-            pre_expire = set(sched.active_slots) if self.paged else set()
-            sched.expire(step_i)
-            active_now = set(sched.active_slots)
-            if self.paged:
-                for s_ in pre_expire - active_now:
-                    pager.release(s_)
-                    clears.append(s_)
-            slot_tier = {s: t for s, t in slot_tier.items() if s in active_now}
-            if not sched.servable:
-                for rid in sched.pending_rids:
-                    sched.drop_pending(
-                        rid,
-                        "unservable: every decode slot is quarantined",
-                    )
-                continue
-            if injector is not None:
-                self.q_params, cache = injector.apply(
-                    step_i, self.q_params, cache
-                )
-            if check and self.audit_interval:
-                # at-rest audits vs the post-commit baselines of the last
-                # iteration: params fingerprint + per-slot KV checksums
-                if step_i % self.audit_interval == 0:
-                    integ["audits"] += 1
-                    if self._audit_params():
-                        integ["audit_alarms"] += 1
-                        if injector is not None:
-                            injector.mark_detected("params", step_i)
-                        if scrub_mode:
-                            self._scrub()
+            # Each phase of the iteration runs inside its own profiler
+            # span, in this order and never overlapping: serve.control,
+            # .admit, .chunk, .commit, .decode, .pull, .record. Admit,
+            # chunk and commit appear only in iterations that do that work.
+            with TraceAnnotation("serve.control"):
+                pre_expire = set(sched.active_slots) if self.paged else set()
+                sched.expire(step_i)
+                active_now = set(sched.active_slots)
                 if self.paged:
-                    sums = tuple(np.asarray(x) for x in self._paged_fp(cache))
-                    integ["kv_checks"] += 1
-                    bad_pages = [
-                        int(p)
-                        for p in np.flatnonzero(sums[0] != kv_ref[0])
-                        if p != 0  # null page: free lanes scatter there
-                    ]
-                    bad_meta = np.flatnonzero(sums[1] != kv_ref[1]).tolist()
-                    if bad_pages or bad_meta:
-                        integ["kv_alarms"] += len(bad_pages) + len(bad_meta)
-                        if injector is not None:
-                            injector.mark_detected("kv", step_i)
-                        if scrub_mode:
-                            # page -> holders: requeue live tenants, drop
-                            # registry entries, quarantine repeat offenders
-                            affected = set(bad_meta)
-                            for pid in bad_pages:
-                                integ["page_faults"] += 1
-                                page_faults[pid] = page_faults.get(pid, 0) + 1
-                                if registry is not None:
-                                    registry.drop_page(pid)
-                                affected.update(pager.slots_holding(pid))
-                                if page_faults[pid] >= self.quarantine_after:
-                                    allocator.quarantine(pid)
-                                    integ["pages_quarantined"] += 1
-                            if job is not None and job.slot in affected:
-                                affected.discard(job.slot)
-                                # a fault on a page the job merely MAPS
-                                # (shared prefix) poisons data it will
-                                # decode against — abort and resubmit; a
-                                # fault on an OWNED page is overwritten
-                                # wholesale by the commit scatter
-                                shared_held = set(pager.pages(job.slot)) - set(
-                                    pager.owned_pages(job.slot)
-                                )
-                                if shared_held & set(bad_pages):
-                                    jslot = job.slot
-                                    slot_faults[jslot] = (
-                                        slot_faults.get(jslot, 0) + 1
-                                    )
-                                    backoff = 1 << min(slot_faults[jslot], 4)
-                                    pager.release(jslot)
-                                    clears.append(jslot)
-                                    sched.unreserve(jslot)
-                                    rid = sched.resubmit(
-                                        job.req, step_i + backoff
-                                    )
-                                    integ["requeued"] += 1
-                                    if sched.retries(rid) > self.max_retries:
-                                        sched.drop_pending(
-                                            rid,
-                                            "retry budget exhausted: "
-                                            f"{sched.retries(rid)} KV faults "
-                                            f"on request {rid}",
-                                        )
-                                    job = None
-                            self._contain_kv(
-                                sched, sorted(affected), slot_faults,
-                                step_i, integ,
-                            )
-                            for s_ in active_now - set(sched.active_slots):
-                                pager.release(s_)
-                                clears.append(s_)
-                            active_now = set(sched.active_slots)
-                            slot_tier = {
-                                s: t for s, t in slot_tier.items()
-                                if s in active_now
-                            }
-                        kv_ref = sums  # re-baseline: corrupt pages are dead
-                        # (tenants requeued, registry entries dropped)
-                else:
-                    sums = np.asarray(self._slot_fp(cache))
-                    integ["kv_checks"] += 1
-                    bad_slots = np.flatnonzero(sums != kv_ref).tolist()
-                    if bad_slots:
-                        integ["kv_alarms"] += len(bad_slots)
-                        if injector is not None:
-                            injector.mark_detected("kv", step_i)
-                        if scrub_mode:
-                            self._contain_kv(
-                                sched, bad_slots, slot_faults, step_i, integ
-                            )
-                            active_now = set(sched.active_slots)
-                            slot_tier = {
-                                s: t for s, t in slot_tier.items()
-                                if s in active_now
-                            }
-                        kv_ref = sums  # re-baseline (corrupt extents are dead:
-                        # their tenants were requeued; readmission overwrites)
-            decision = None
-            if ap is not None:
-                decision = ap.observe(
-                    step_i,
-                    sched.queue_depth(step_i),
-                    scrubs=self._scrubs - scrubs0,
-                    step_latency_s=last_latency,
-                    tokens_emitted=last_emitted,
-                    shadow_kl=pending_kl,
-                )
-                pending_kl = None
-                if decision.switched:
-                    switches.append((decode_steps, ap.tier))
-            due = [s for s in schedule if s <= decode_steps]
-            for s in sorted(due):
-                prec = schedule.pop(s)
-                if ap is None:
-                    # legacy open-loop semantics: a scheduled switch
-                    # rebinds the global dial, in-flight slots included
-                    self.set_precision(prec)
-                    switches.append((decode_steps, self._precision))
-                elif decision is not None and decision.switched:
-                    # race: both landed on this decode step — the
-                    # closed-loop controller wins, the entry is consumed
-                    schedule_conflicts.append((decode_steps, s, prec))
-                else:
-                    forced = ap.force(step_i, _norm_precision(prec))
-                    if forced.switched:
-                        switches.append((decode_steps, ap.tier))
-            if ap is not None and ap.shedding:
-                waiting = sched.waiting(step_i)
-                if waiting:
-                    est = max(
-                        1,
-                        round(
-                            sum(r.max_new_tokens for r in waiting)
-                            / len(waiting)
-                        ),
-                    )
-                    for rid in ap.shed_victims(
-                        waiting, step_i, service_estimate=est
-                    ):
-                        sched.shed(
+                    for s_ in pre_expire - active_now:
+                        pager.release(s_)
+                        clears.append(s_)
+                slot_tier = {s: t for s, t in slot_tier.items() if s in active_now}
+                if not sched.servable:
+                    for rid in sched.pending_rids:
+                        sched.drop_pending(
                             rid,
-                            f"overload: shed from queue tail at step "
-                            f"{step_i} (autopilot, tier w{ap.tier[1]})",
+                            "unservable: every decode slot is quarantined",
                         )
+                    continue
+                if injector is not None:
+                    self.q_params, cache = injector.apply(
+                        step_i, self.q_params, cache
+                    )
+                if check and self.audit_interval:
+                    # at-rest audits vs the post-commit baselines of the last
+                    # iteration: params fingerprint + per-slot KV checksums
+                    if step_i % self.audit_interval == 0:
+                        integ["audits"] += 1
+                        if self._audit_params():
+                            integ["audit_alarms"] += 1
+                            if injector is not None:
+                                injector.mark_detected("params", step_i)
+                            if scrub_mode:
+                                self._scrub()
+                    if self.paged:
+                        sums = tuple(np.asarray(x) for x in self._paged_fp(cache))
+                        integ["kv_checks"] += 1
+                        bad_pages = [
+                            int(p)
+                            for p in np.flatnonzero(sums[0] != kv_ref[0])
+                            if p != 0  # null page: free lanes scatter there
+                        ]
+                        bad_meta = np.flatnonzero(sums[1] != kv_ref[1]).tolist()
+                        if bad_pages or bad_meta:
+                            integ["kv_alarms"] += len(bad_pages) + len(bad_meta)
+                            if injector is not None:
+                                injector.mark_detected("kv", step_i)
+                            if scrub_mode:
+                                # page -> holders: requeue live tenants, drop
+                                # registry entries, quarantine repeat offenders
+                                affected = set(bad_meta)
+                                for pid in bad_pages:
+                                    integ["page_faults"] += 1
+                                    page_faults[pid] = page_faults.get(pid, 0) + 1
+                                    if registry is not None:
+                                        registry.drop_page(pid)
+                                    affected.update(pager.slots_holding(pid))
+                                    if page_faults[pid] >= self.quarantine_after:
+                                        allocator.quarantine(pid)
+                                        integ["pages_quarantined"] += 1
+                                if job is not None and job.slot in affected:
+                                    affected.discard(job.slot)
+                                    # a fault on a page the job merely MAPS
+                                    # (shared prefix) poisons data it will
+                                    # decode against — abort and resubmit; a
+                                    # fault on an OWNED page is overwritten
+                                    # wholesale by the commit scatter
+                                    shared_held = set(pager.pages(job.slot)) - set(
+                                        pager.owned_pages(job.slot)
+                                    )
+                                    if shared_held & set(bad_pages):
+                                        jslot = job.slot
+                                        slot_faults[jslot] = (
+                                            slot_faults.get(jslot, 0) + 1
+                                        )
+                                        backoff = 1 << min(slot_faults[jslot], 4)
+                                        pager.release(jslot)
+                                        clears.append(jslot)
+                                        sched.unreserve(jslot)
+                                        rid = sched.resubmit(
+                                            job.req, step_i + backoff
+                                        )
+                                        integ["requeued"] += 1
+                                        if sched.retries(rid) > self.max_retries:
+                                            sched.drop_pending(
+                                                rid,
+                                                "retry budget exhausted: "
+                                                f"{sched.retries(rid)} KV faults "
+                                                f"on request {rid}",
+                                            )
+                                        job = None
+                                self._contain_kv(
+                                    sched, sorted(affected), slot_faults,
+                                    step_i, integ,
+                                )
+                                for s_ in active_now - set(sched.active_slots):
+                                    pager.release(s_)
+                                    clears.append(s_)
+                                active_now = set(sched.active_slots)
+                                slot_tier = {
+                                    s: t for s, t in slot_tier.items()
+                                    if s in active_now
+                                }
+                            kv_ref = sums  # re-baseline: corrupt pages are dead
+                            # (tenants requeued, registry entries dropped)
+                    else:
+                        sums = np.asarray(self._slot_fp(cache))
+                        integ["kv_checks"] += 1
+                        bad_slots = np.flatnonzero(sums != kv_ref).tolist()
+                        if bad_slots:
+                            integ["kv_alarms"] += len(bad_slots)
+                            if injector is not None:
+                                injector.mark_detected("kv", step_i)
+                            if scrub_mode:
+                                self._contain_kv(
+                                    sched, bad_slots, slot_faults, step_i, integ
+                                )
+                                active_now = set(sched.active_slots)
+                                slot_tier = {
+                                    s: t for s, t in slot_tier.items()
+                                    if s in active_now
+                                }
+                            kv_ref = sums  # re-baseline (corrupt extents are dead:
+                            # their tenants were requeued; readmission overwrites)
+                decision = None
+                if ap is not None:
+                    decision = ap.observe(
+                        step_i,
+                        sched.queue_depth(step_i),
+                        scrubs=self._scrubs - scrubs0,
+                        step_latency_s=last_latency,
+                        tokens_emitted=last_emitted,
+                        shadow_kl=pending_kl,
+                    )
+                    pending_kl = None
+                    if decision.switched:
+                        switches.append((decode_steps, ap.tier))
+                due = [s for s in schedule if s <= decode_steps]
+                for s in sorted(due):
+                    prec = schedule.pop(s)
+                    if ap is None:
+                        # legacy open-loop semantics: a scheduled switch
+                        # rebinds the global dial, in-flight slots included
+                        self.set_precision(prec)
+                        switches.append((decode_steps, self._precision))
+                    elif decision is not None and decision.switched:
+                        # race: both landed on this decode step — the
+                        # closed-loop controller wins, the entry is consumed
+                        schedule_conflicts.append((decode_steps, s, prec))
+                    else:
+                        forced = ap.force(step_i, _norm_precision(prec))
+                        if forced.switched:
+                            switches.append((decode_steps, ap.tier))
+                if ap is not None and ap.shedding:
+                    waiting = sched.waiting(step_i)
+                    if waiting:
+                        est = max(
+                            1,
+                            round(
+                                sum(r.max_new_tokens for r in waiting)
+                                / len(waiting)
+                            ),
+                        )
+                        for rid in ap.shed_victims(
+                            waiting, step_i, service_estimate=est
+                        ):
+                            sched.shed(
+                                rid,
+                                f"overload: shed from queue tail at step "
+                                f"{step_i} (autopilot, tier w{ap.tier[1]})",
+                            )
             if self._staged:
-                # flush deferred clears BEFORE admission: pages released
-                # since the last flush may be reallocated and committed
-                # into right below, and the releasing slot's table must
-                # point at the null page before that happens
-                for s_ in clears:
-                    cache = self._clear_slot(cache, jnp.int32(s_))
-                clears.clear()
-                if (
+                expired = (
                     job is not None
                     and job.req.deadline_step is not None
                     and step_i >= job.req.deadline_step
-                ):
-                    if self.paged:
-                        pager.release(job.slot)
-                        clears.append(job.slot)
-                    sched.unreserve(job.slot)
-                    sched.fail(
-                        job.req.rid,
-                        f"deadline: staged prefill expired at step {step_i}",
-                    )
-                    job = None
-                if job is None:
-                    for slot, req in sched.admissible(
-                        step_i, capacity=_capacity
-                    ):
-                        # tier is a per-request contract fixed at
-                        # admission, like the dense path
-                        tier_index = ap.tier_index if ap is not None else 0
-                        job = self._open_job(
-                            slot, req, sched, pager, registry, tier_index
-                        )
-                        shared_hits += int(job.from_hit)
-                        if self.prefill_chunk > 0:
-                            # chunked: ONE job in flight, one chunk per
-                            # engine iteration — decode keeps its cadence
-                            # while the prefill burst drains in slices
-                            break
-                        # monolithic staging: run to completion now, so
-                        # admission timing matches the dense engine
-                        done = False
-                        while not done:
-                            done = self._job_step(job, integ)
-                            prefill_chunks += 1
-                        cache, tokens, done_now = self._job_commit(
-                            job, cache, tokens, sched, pager, registry
-                        )
-                        if ap is not None:
-                            request_tiers[job.req.rid] = self._tiers[
-                                job.tier_index
-                            ]
-                            if not done_now:
-                                slot_tier[job.slot] = job.tier_index
-                        job = None
+                )
+                if clears or expired or job is None:
+                    with TraceAnnotation("serve.admit") as span:
+                        # flush deferred clears BEFORE admission: pages released
+                        # since the last flush may be reallocated and committed
+                        # into right below, and the releasing slot's table must
+                        # point at the null page before that happens
+                        for s_ in clears:
+                            cache = self._clear_slot(cache, jnp.int32(s_))
+                        clears.clear()
+                        if expired:
+                            if self.paged:
+                                pager.release(job.slot)
+                                clears.append(job.slot)
+                            sched.unreserve(job.slot)
+                            sched.fail(
+                                job.req.rid,
+                                f"deadline: staged prefill expired at step {step_i}",
+                            )
+                            job = None
+                        if job is None:
+                            for slot, req in sched.admissible(
+                                step_i, capacity=_capacity
+                            ):
+                                span.set_metadata(rid=req.rid, slot=slot)
+                                # tier is a per-request contract fixed at
+                                # admission, like the dense path
+                                tier_index = ap.tier_index if ap is not None else 0
+                                job = self._open_job(
+                                    slot, req, sched, pager, registry, tier_index
+                                )
+                                shared_hits += int(job.from_hit)
+                                if self.prefill_chunk > 0:
+                                    # chunked: ONE job in flight, one chunk per
+                                    # engine iteration — decode keeps its cadence
+                                    # while the prefill burst drains in slices
+                                    break
+                                # monolithic staging: run to completion now, so
+                                # admission timing matches the dense engine
+                                done = False
+                                while not done:
+                                    done = self._job_step(job, integ)
+                                    prefill_chunks += 1
+                                cache, tokens, done_now = self._job_commit(
+                                    job, cache, tokens, sched, pager, registry
+                                )
+                                if ap is not None:
+                                    request_tiers[job.req.rid] = self._tiers[
+                                        job.tier_index
+                                    ]
+                                    if not done_now:
+                                        slot_tier[job.slot] = job.tier_index
+                                job = None
                 if job is not None:
-                    if self._job_step(job, integ):
-                        cache, tokens, done_now = self._job_commit(
-                            job, cache, tokens, sched, pager, registry
-                        )
+                    ids = {"rid": job.req.rid, "slot": job.slot}
+                    with TraceAnnotation("serve.chunk", **ids):
+                        done = self._job_step(job, integ)
+                    prefill_chunks += 1
+                    if done:
+                        with TraceAnnotation("serve.commit", **ids):
+                            cache, tokens, done_now = self._job_commit(
+                                job, cache, tokens, sched, pager, registry
+                            )
                         if ap is not None:
                             request_tiers[job.req.rid] = self._tiers[
                                 job.tier_index
@@ -1445,123 +1460,128 @@ class ContinuousBatchingEngine(_PrecisionDial, _IntegrityRuntime, _PlanTuning):
                             if not done_now:
                                 slot_tier[job.slot] = job.tier_index
                         job = None
-                    prefill_chunks += 1
             else:
-                for slot, req in sched.admissible(step_i):
-                    # tier is a per-request contract fixed at admission:
-                    # the prefill AND every decode step run at this tier,
-                    # across any later controller transitions
-                    tier_steps = (
-                        self._steps_for(self._tier_precision(ap.tier_index))
-                        if ap is not None
-                        else None
-                    )
-                    logits, seq_cache = self._prefill_checked(
-                        req, integ if check else None, steps=tier_steps
-                    )
-                    tok = self._first_token(logits, req)
-                    cache = self._insert(cache, seq_cache, jnp.int32(slot))
-                    tokens = tokens.at[slot, 0].set(tok)
-                    done_now = sched.start(slot, req, int(tok))
-                    if ap is not None:
-                        request_tiers[req.rid] = ap.tier
-                        if not done_now:
-                            slot_tier[slot] = ap.tier_index
-            if sched.active_slots:
-                t_step = time.time()
-                key = jax.random.fold_in(self._decode_key, step_i)
-                temps = jnp.asarray(sched.temperatures())
-                active = sched.active_slots
-                if ap is None:
-                    ntok, ncache = self._decode_pass(
-                        self._steps_for(self._precision), cache, tokens,
-                        temps, key, step_i, integ, injector,
-                    )
-                else:
-                    # mixed-tier decode: one plane-prefix pass per tier
-                    # present among the active slots, all against the
-                    # same pre-step cache; each slot keeps the pass of
-                    # its contract tier (free slots ride the base pass —
-                    # their lanes are garbage the scheduler never reads)
-                    present = sorted({slot_tier.get(s, 0) for s in active})
-                    ntok, ncache = self._decode_pass(
-                        self._steps_for(self._tier_precision(present[0])),
-                        cache, tokens, temps, key, step_i, integ, injector,
-                    )
-                    for ti in present[1:]:
-                        tok_t, cache_t = self._decode_pass(
-                            self._steps_for(self._tier_precision(ti)),
-                            cache, tokens, temps, key, step_i, integ,
-                            injector,
+                with TraceAnnotation("serve.admit") as span:
+                    for slot, req in sched.admissible(step_i):
+                        span.set_metadata(rid=req.rid, slot=slot)
+                        # tier is a per-request contract fixed at admission:
+                        # the prefill AND every decode step run at this tier,
+                        # across any later controller transitions
+                        tier_steps = (
+                            self._steps_for(self._tier_precision(ap.tier_index))
+                            if ap is not None
+                            else None
                         )
-                        mask_np = np.zeros((self.n_slots,), bool)
-                        for s_ in active:
-                            if slot_tier.get(s_, 0) == ti:
-                                mask_np[s_] = True
-                        mask = jnp.asarray(mask_np)
-                        ntok = jnp.where(mask[:, None], tok_t, ntok)
-                        if self.paged:
-                            # pool leaves merge per PHYSICAL page: take
-                            # this tier's writes only on pages owned by
-                            # its slots (decode never writes shared pages)
-                            pmask_np = np.zeros((self._kv_pages,), bool)
+                        logits, seq_cache = self._prefill_checked(
+                            req, integ if check else None, steps=tier_steps
+                        )
+                        tok = self._first_token(logits, req)
+                        cache = self._insert(cache, seq_cache, jnp.int32(slot))
+                        tokens = tokens.at[slot, 0].set(tok)
+                        done_now = sched.start(slot, req, int(tok))
+                        if ap is not None:
+                            request_tiers[req.rid] = ap.tier
+                            if not done_now:
+                                slot_tier[slot] = ap.tier_index
+            active = sched.active_slots
+            if active:
+                with TraceAnnotation("serve.decode"):
+                    t_step = time.time()
+                    key = jax.random.fold_in(self._decode_key, step_i)
+                    temps = jnp.asarray(sched.temperatures())
+                    if ap is None:
+                        ntok, ncache = self._decode_pass(
+                            self._steps_for(self._precision), cache, tokens,
+                            temps, key, step_i, integ, injector,
+                        )
+                    else:
+                        # mixed-tier decode: one plane-prefix pass per tier
+                        # present among the active slots, all against the
+                        # same pre-step cache; each slot keeps the pass of
+                        # its contract tier (free slots ride the base pass —
+                        # their lanes are garbage the scheduler never reads)
+                        present = sorted({slot_tier.get(s, 0) for s in active})
+                        ntok, ncache = self._decode_pass(
+                            self._steps_for(self._tier_precision(present[0])),
+                            cache, tokens, temps, key, step_i, integ, injector,
+                        )
+                        for ti in present[1:]:
+                            tok_t, cache_t = self._decode_pass(
+                                self._steps_for(self._tier_precision(ti)),
+                                cache, tokens, temps, key, step_i, integ,
+                                injector,
+                            )
+                            mask_np = np.zeros((self.n_slots,), bool)
                             for s_ in active:
                                 if slot_tier.get(s_, 0) == ti:
-                                    for pid in pager.owned_pages(s_):
-                                        pmask_np[pid] = True
-                            ncache = self._select_paged(
-                                ncache, cache_t, mask, jnp.asarray(pmask_np)
+                                    mask_np[s_] = True
+                            mask = jnp.asarray(mask_np)
+                            ntok = jnp.where(mask[:, None], tok_t, ntok)
+                            if self.paged:
+                                # pool leaves merge per PHYSICAL page: take
+                                # this tier's writes only on pages owned by
+                                # its slots (decode never writes shared pages)
+                                pmask_np = np.zeros((self._kv_pages,), bool)
+                                for s_ in active:
+                                    if slot_tier.get(s_, 0) == ti:
+                                        for pid in pager.owned_pages(s_):
+                                            pmask_np[pid] = True
+                                ncache = self._select_paged(
+                                    ncache, cache_t, mask, jnp.asarray(pmask_np)
+                                )
+                            else:
+                                ncache = self._select(ncache, cache_t, mask)
+                        frac = ap.policy.shadow_frac
+                        if (
+                            frac > 0.0
+                            and ap.tier_index > 0
+                            and int(decode_steps * frac)
+                            > int((decode_steps - 1) * frac)
+                        ):
+                            # shadow quality probe against the pre-step state
+                            pending_kl = self._shadow_kl(
+                                cache, tokens, temps, key, ap.tier_index, active
                             )
-                        else:
-                            ncache = self._select(ncache, cache_t, mask)
-                    frac = ap.policy.shadow_frac
-                    if (
-                        frac > 0.0
-                        and ap.tier_index > 0
-                        and int(decode_steps * frac)
-                        > int((decode_steps - 1) * frac)
-                    ):
-                        # shadow quality probe against the pre-step state
-                        pending_kl = self._shadow_kl(
-                            cache, tokens, temps, key, ap.tier_index, active
-                        )
-                        shadow_probes += 1
-                tokens, cache = ntok, ncache
-                toks_np = np.asarray(tokens[:, 0])
-                for slot in active:
-                    if ap is not None:
-                        ti = slot_tier.get(slot, 0)
-                        tier_tokens[ti] = tier_tokens.get(ti, 0) + 1
-                    evicted = sched.record(slot, int(toks_np[slot]))
-                    decoded_tokens += 1
-                    if evicted and self.paged:
-                        # free the pages now (host-side); the device-side
-                        # null-page clear flushes before the next admission
-                        pager.release(slot)
-                        clears.append(slot)
-                last_latency = time.time() - t_step
-                last_emitted = len(active)
-                sched.observe_step(step_i, last_latency)
-                decode_iter_lat.append(time.time() - t_iter)
-                decode_steps += 1
-                step_i += 1
-            else:
-                # nothing in flight: fast-forward to the next arrival
-                sched.observe_step(step_i)
-                last_latency = float("nan")
-                last_emitted = 0
-                nxt = sched.next_arrival()
-                if job is not None:
-                    # a staged prefill is progressing: no idle fast-forward
-                    # (it would burn the job's deadline on skipped steps)
+                            shadow_probes += 1
+                    tokens, cache = ntok, ncache
+                with TraceAnnotation("serve.pull"):
+                    toks_np = np.asarray(tokens[:, 0])
+            with TraceAnnotation("serve.record"):
+                if active:
+                    for slot in active:
+                        if ap is not None:
+                            ti = slot_tier.get(slot, 0)
+                            tier_tokens[ti] = tier_tokens.get(ti, 0) + 1
+                        evicted = sched.record(slot, int(toks_np[slot]))
+                        decoded_tokens += 1
+                        if evicted and self.paged:
+                            # free the pages now (host-side); the device-side
+                            # null-page clear flushes before the next admission
+                            pager.release(slot)
+                            clears.append(slot)
+                    last_latency = time.time() - t_step
+                    last_emitted = len(active)
+                    sched.observe_step(step_i, last_latency)
+                    decode_iter_lat.append(time.time() - t_iter)
+                    decode_steps += 1
                     step_i += 1
                 else:
-                    step_i = step_i + 1 if nxt is None else max(nxt, step_i + 1)
-            if check and self.audit_interval:
-                if self.paged:
-                    kv_ref = tuple(np.asarray(x) for x in self._paged_fp(cache))
-                else:
-                    kv_ref = np.asarray(self._slot_fp(cache))
+                    # nothing in flight: fast-forward to the next arrival
+                    sched.observe_step(step_i)
+                    last_latency = float("nan")
+                    last_emitted = 0
+                    nxt = sched.next_arrival()
+                    if job is not None:
+                        # a staged prefill is progressing: no idle fast-forward
+                        # (it would burn the job's deadline on skipped steps)
+                        step_i += 1
+                    else:
+                        step_i = step_i + 1 if nxt is None else max(nxt, step_i + 1)
+                if check and self.audit_interval:
+                    if self.paged:
+                        kv_ref = tuple(np.asarray(x) for x in self._paged_fp(cache))
+                    else:
+                        kv_ref = np.asarray(self._slot_fp(cache))
         jax.block_until_ready(tokens)
         wall = max(time.time() - t0, 1e-9)
         s = sched.stats()

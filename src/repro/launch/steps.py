@@ -11,7 +11,17 @@ quantizing at the lower width. Bit-widths are trace-time constants
 (exactly as the accelerator's effective width is a register programmed
 between matmuls), so each dialed precision is its own jit specialization;
 the serving engines keep one compiled step per precision and swap between
-them mid-flight (``set_precision``)."""
+them mid-flight (``set_precision``).
+
+A device trace names each compiled step after its function: ``jax.jit``
+calls the XLA module ``jit_<function name>``, so the continuous-batching
+decode step shows as ``jit_cb_step`` and a chunk of staged prefill as
+``jit_chunk_step`` (every chunk length and tier). The benchmark's
+per-step device times match those names: keep ``cb_step`` and
+``chunk_step`` as the inner functions' names. Inside the steps,
+``jax.named_scope`` marks the model's parts in each op's metadata
+(``attention``, ``mlp``, ``lm_head``, ``sampling``; ``paged_commit`` in
+:func:`repro.models.paging.paged_commit`)."""
 
 from __future__ import annotations
 
@@ -275,8 +285,9 @@ def make_serve_step(
         (logits, cache), alarms = _collected(
             collector, lambda: decode(params, cache, {"tokens": tokens})
         )
-        logits = sampling.mask_vocab(logits, cfg.vocab_size)
-        next_tok = sample_fn(logits, key)[:, None]
+        with jax.named_scope("sampling"):
+            logits = sampling.mask_vocab(logits, cfg.vocab_size)
+            next_tok = sample_fn(logits, key)[:, None]
         if collector is None:
             return next_tok, cache
         return next_tok, cache, alarms
@@ -481,8 +492,9 @@ def make_cb_decode_step(
             collector, lambda: decode(params, cache, {"tokens": tokens})
         )
         raw_logits = logits
-        logits = sampling.mask_vocab(logits, cfg.vocab_size)
-        next_tok = sampling.sample_tokens(logits, temps, key)[:, None]
+        with jax.named_scope("sampling"):
+            logits = sampling.mask_vocab(logits, cfg.vocab_size)
+            next_tok = sampling.sample_tokens(logits, temps, key)[:, None]
         out = (next_tok, cache)
         if collector is not None:
             out = out + (alarms,)

@@ -475,8 +475,9 @@ def paged_commit(cache, scratch, slot, page_ids, write_mask, length):
             out["block_table"] = pblk["block_table"].at[slot].set(page_ids)
         return out
 
-    step = cache["step"].at[slot].set(length)
-    return _map_blocks(commit_block, step, cache, scratch)
+    with jax.named_scope("paged_commit"):
+        step = cache["step"].at[slot].set(length)
+        return _map_blocks(commit_block, step, cache, scratch)
 
 
 def clear_slot(cache, slot):

@@ -100,23 +100,26 @@ def _apply_block(
     aux = jnp.float32(0.0)
     if kind in ("dense", "attn", "moe"):
         h = rmsnorm_apply(params["attn_norm"], x)
-        attn_out, new_attn_cache = attention_apply(
-            params["attn"],
-            h,
-            positions,
-            n_heads=cfg.n_heads,
-            n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim,
-            causal=cfg.causal,
-            window=cfg.local_window if kind == "attn" else 0,
-            rope_theta=cfg.rope_theta,
-            qk_norm=cfg.qk_norm,
-            chunk=cfg.attn_chunk,
-            policy=policy,
-            training=training,
-            name=f"{name}/attn",
-            cache=cache,
-        )
+        # named scopes set only op metadata: a device trace can split a
+        # step's time by part of the model
+        with jax.named_scope("attention"):
+            attn_out, new_attn_cache = attention_apply(
+                params["attn"],
+                h,
+                positions,
+                n_heads=cfg.n_heads,
+                n_kv_heads=cfg.n_kv_heads,
+                head_dim=cfg.head_dim,
+                causal=cfg.causal,
+                window=cfg.local_window if kind == "attn" else 0,
+                rope_theta=cfg.rope_theta,
+                qk_norm=cfg.qk_norm,
+                chunk=cfg.attn_chunk,
+                policy=policy,
+                training=training,
+                name=f"{name}/attn",
+                cache=cache,
+            )
         # Reduce-scatter the block output before the residual add (Megatron
         # sequence parallelism); the mirrored constraint also pins the
         # backward cotangent to seq-sharded, keeping weight grads shard-local.
@@ -124,23 +127,24 @@ def _apply_block(
         x = x + attn_out
         x = constrain(x, ("batch", "seq", None))
         h = rmsnorm_apply(params["mlp_norm"], x)
-        if kind == "moe":
-            mlp_out, aux = moe_apply(
-                params["moe"],
-                h,
-                n_experts=cfg.n_experts,
-                top_k=cfg.top_k,
-                policy=policy,
-                training=training,
-                name=f"{name}/moe",
-                impl=cfg.moe_impl,
-                capacity_factor=cfg.moe_capacity_factor,
-            )
-        else:
-            mlp_out = mlp_apply(
-                params["mlp"], h, act=cfg.act, policy=policy, training=training,
-                name=f"{name}/mlp",
-            )
+        with jax.named_scope("mlp"):
+            if kind == "moe":
+                mlp_out, aux = moe_apply(
+                    params["moe"],
+                    h,
+                    n_experts=cfg.n_experts,
+                    top_k=cfg.top_k,
+                    policy=policy,
+                    training=training,
+                    name=f"{name}/moe",
+                    impl=cfg.moe_impl,
+                    capacity_factor=cfg.moe_capacity_factor,
+                )
+            else:
+                mlp_out = mlp_apply(
+                    params["mlp"], h, act=cfg.act, policy=policy, training=training,
+                    name=f"{name}/mlp",
+                )
         mlp_out = constrain(mlp_out, ("batch", "seq", None))
         x = x + mlp_out
         x = constrain(x, ("batch", "seq", None))
@@ -449,12 +453,13 @@ def forward(
     if last_only:
         x = x[:, -1:]
     x = rmsnorm_apply(params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = x.astype(jnp.float32) @ params["embed"]["embedding"].astype(jnp.float32).T
-    else:
-        logits = lm_head_apply(
-            params["lm_head"], x, policy=policy, training=training
-        )
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            logits = x.astype(jnp.float32) @ params["embed"]["embedding"].astype(jnp.float32).T
+        else:
+            logits = lm_head_apply(
+                params["lm_head"], x, policy=policy, training=training
+            )
     logits = constrain(logits, ("batch", None, "vocab"))
     return logits, aux, new_cache
 
