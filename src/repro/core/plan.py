@@ -362,6 +362,8 @@ def _build_plan(key: PlanKey, registry: "PlanRegistry") -> "MatmulPlan":
     gate = key.sparsity in ("gate", "compact") and kernel in (
         "fused_cached", "fused_repack", "cached_packed", "staged_packed"
     ) and key.backend != "jnp"
+    if not tuned and key.bn is None and kernel.startswith("fused") and not gate:
+        bn = ops.fused_bn(key.n)  # one MXU pass a tile: a wide step pays
 
     a_shift = key.a_in_bits - key.a_bits
     requant_w = w_shift > 0 and kernel in (
@@ -687,18 +689,24 @@ class MatmulPlan:
         the executed width, ``pair_passes_after_compaction`` what survives
         the cache's plane compaction, ``pair_passes_executed`` what the
         weight-occupancy gate leaves. Dynamic activation-side gating skips
-        strictly more at run time and is not counted here. Without a
-        packed bit-plane cache only the mode/route fields are reported.
+        strictly more at run time and is not counted here.
+        ``mxu_passes_per_tile`` says which path the plan engages: 1 on the
+        fused routes' single int8 pass, ``a_planes x planes`` on every
+        plane-pair loop (the gated fused kernel among them). Without a
+        packed bit-plane cache only the mode/route fields are reported,
+        with the dense plane count.
         """
         import numpy as np
 
         key = self.key
+        single_pass = self.kernel in ("fused_cached", "fused_repack") and not self.gate
         out = {
             "mode": key.sparsity,
             "kernel": self.kernel,
             "gated": self.gate,
             "planes_dense": key.w_bits,
             "a_planes": key.a_bits,
+            "mxu_passes_per_tile": 1 if single_pass else key.a_bits * key.w_bits,
         }
         if (
             w_planes is None
@@ -719,6 +727,7 @@ class MatmulPlan:
         dense = key.a_bits * key.w_bits * nk
         executed = key.a_bits * occupied
         out.update(
+            mxu_passes_per_tile=1 if single_pass else key.a_bits * n_kept,
             planes_kept=n_kept,
             k_tiles=nk,
             pair_passes_dense=dense,

@@ -97,6 +97,36 @@ PACKED_WORD_BITS = 32
 VMEM_BUDGET_BYTES = 8 * 2**20
 
 
+#: the fused kernel's grid step (one int8 MXU pass a tile, so each step
+#: costs moving and rebuilding its weight words plus a fixed overhead).
+#: Measured on one v5e at LLaMA-7B's linears, M 32 and 64: bn 256 with one
+#: pack block a step reached 36-45% of the HBM roofline, the widest steps
+#: tried (bn up to 1024, bn x blocks up to 2048 columns) 57-82%.
+FUSED_MAX_BN = 1024
+FUSED_MAX_K_BLOCKS = 4
+FUSED_MAX_STEP_COLS = 2048
+
+
+def fused_bn(n: int) -> int:
+    """The fused kernel's output tile for N: the widest lane multiple, up to
+    ``FUSED_MAX_BN``, that divides N rounded up to the lane width — so no
+    weight word is padded past that per call."""
+    lanes = -(-n // MOSAIC_LANE)
+    return MOSAIC_LANE * max(
+        c for c in range(1, FUSED_MAX_BN // MOSAIC_LANE + 1) if lanes % c == 0
+    )
+
+
+def fused_k_blocks(bn: int, n_blocks: int) -> int:
+    """Pack blocks per K step of the single-pass fused kernel: the most, up
+    to ``FUSED_MAX_K_BLOCKS`` and ``FUSED_MAX_STEP_COLS // bn``, that
+    divide the weight's ``n_blocks`` pack blocks."""
+    return max(
+        c for c in range(1, FUSED_MAX_K_BLOCKS + 1)
+        if n_blocks % c == 0 and bn * c <= FUSED_MAX_STEP_COLS
+    )
+
+
 def tiles_legal(
     bm: int,
     bn: int,
@@ -260,9 +290,12 @@ def fused_linear(
     returns the raw int32 accumulator — the pre-epilogue parity mode).
     The jnp backend is the staged parity oracle: decompose +
     :func:`ref.plane_matmul_ref` + :func:`apply_epilogue`, bit-identical
-    pre-epilogue. ``bn=None`` derives the output tile from N (the decode
-    path's wide-tile heuristic — see :func:`auto_tiles`). ``gate``:
-    occupancy-gated sparse plane execution (ignored by the jnp oracle).
+    pre-epilogue. The kernel makes one int8 MXU pass per K step over the
+    tier's integer weights, rebuilt in VMEM from the packed words; its
+    grid step comes from the shape (:func:`fused_bn` when ``bn=None``,
+    :func:`fused_k_blocks`). ``gate``: occupancy-gated sparse plane
+    execution, the plane-pair loop, one pack block a step and the
+    :func:`auto_tiles` ``bn`` (ignored by the jnp oracle).
     """
     backend = resolve_backend(backend)
     pair_w = bs._wrap_weights(
@@ -275,11 +308,17 @@ def fused_linear(
         return acc if epilogue is None else apply_epilogue(acc, epilogue)
     m = x_q.shape[0]
     auto_bm = bm is None
-    bm, bn, _ = auto_tiles(m, x_q.shape[1], bm, None, n=packed_w.mag.shape[-1], bn=bn)
+    n = packed_w.mag.shape[-1]
+    bm, gate_bn, _ = auto_tiles(m, x_q.shape[1], bm, None, n=n, bn=bn)
     if auto_bm:
         bm = _int8_bm(bm)  # x_q is an int8 tile
-    kw = dict(a_bits=a_bits, variant=variant, bm=bm, bn=bn, gate=gate,
-              interpret=backend == "interpret")
+    if gate:  # the plane-pair loop: one pack block a step
+        bn, k_blocks = gate_bn, 1
+    else:
+        bn = fused_bn(n) if bn is None else bn
+        k_blocks = fused_k_blocks(bn, packed_w.n_words // (packed_w.block // bp.WORD_BITS))
+    kw = dict(a_bits=a_bits, variant=variant, bm=bm, bn=bn, k_blocks=k_blocks,
+              gate=gate, interpret=backend == "interpret")
     if epilogue is None:
         return _fused_pallas(x_q, packed_w, pair_w, **kw)
     return _fused_pallas(

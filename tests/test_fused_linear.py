@@ -6,6 +6,8 @@ mode for all supported (variant, a_bits, w_bits) configs, and the fused
 epilogue must match the XLA epilogue over the staged accumulator.
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +18,25 @@ from repro.core.precision import PrecisionPolicy
 from repro.kernels import ops, ref
 from repro.layers.linear import linear_apply, linear_init
 from repro.models.quant import quantize_params
+
+
+def _resident_bytes() -> int:
+    try:
+        with open("/proc/self/statm") as fh:
+            return int(fh.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+    except (OSError, ValueError):
+        return 0
+
+
+@pytest.fixture(autouse=True)
+def _drop_compiled():
+    """Interpret-mode kernels compile to large CPU executables, and JAX's
+    caches keep every one: a process running this file grew past 4 GB and
+    crashed in XLA's CPU compiler. Past 1.5 GB resident the caches are
+    dropped (every test would double the file's time)."""
+    yield
+    if _resident_bytes() > 3 << 29:
+        jax.clear_caches()
 
 
 def _operands(rng, m, k, n, a_bits, w_bits):
@@ -315,3 +336,183 @@ def test_default_tiles_handle_decode_shape(rng):
     got = ops.bitserial_matmul(a, w, a_bits=4, w_bits=4, variant="booth",
                                level="bitplane", backend="interpret")
     np.testing.assert_array_equal(got, a.astype(jnp.int32) @ w)
+
+
+# -- the single int8 pass against the oracle and the plane-pair loop ----------
+
+
+def _three_ways(a, packed, a_bits, variant, bm=8, bn=8, epilogue=None):
+    """The single-pass kernel, the gated plane-pair loop (interpret) and
+    the jnp oracle over the same packed operand."""
+    kw = dict(a_bits=a_bits, variant=variant)
+    single = ops.fused_linear(a, packed, epilogue, backend="interpret",
+                              bm=bm, bn=bn, **kw)
+    gated = ops.fused_linear(a, packed, epilogue, backend="interpret",
+                             bm=bm, bn=bn, gate=True, **kw)
+    oracle = ops.fused_linear(a, packed, epilogue, backend="jnp", **kw)
+    return single, gated, oracle
+
+
+def _pack(w, bits, variant, block):
+    return bp.pack_decomposition(bp.to_bitplanes(w, bits, variant), axis=-2,
+                                 variant=variant, block=block)
+
+
+@pytest.mark.parametrize("variant", ["booth", "sbmwc"])
+@pytest.mark.parametrize("a_bits,w_bits", [(8, 8), (6, 6), (4, 4), (8, 4), (8, 6)])
+@pytest.mark.parametrize("m,k,n,block", [(1, 200, 9, 64), (33, 70, 16, 32)])
+def test_single_pass_matches_oracle_and_pair_loop(variant, a_bits, w_bits, m, k, n,
+                                                  block, rng):
+    """One int8 MXU pass a K block gives the plane-pair sum bit for bit:
+    ragged M/K/N, several K blocks, every tier pair the plans route here."""
+    a, w = _operands(rng, m, k, n, a_bits, w_bits)
+    single, gated, oracle = _three_ways(a, _pack(w, w_bits, variant, block),
+                                        a_bits, variant)
+    np.testing.assert_array_equal(single, oracle)
+    np.testing.assert_array_equal(single, gated)
+    np.testing.assert_array_equal(single, a.astype(jnp.int32) @ w)
+
+
+@pytest.mark.parametrize("variant", ["booth", "sbmwc"])
+@pytest.mark.parametrize("to_bits", [6, 4])
+def test_single_pass_truncated_prefix(variant, to_bits, rng):
+    """8->6 and 8->4 prefixes of an 8-bit store (truncate_packed): the
+    rebuilt weights are the requantized integers, Booth's round half up to
+    +2^(b-1) included (planted: 127 rounds up to it at both tiers)."""
+    a, w = _operands(rng, 6, 150, 12, 8, 8)
+    w = w.at[0, :].set(127).at[7, 3].set(-128)
+    packed = bp.truncate_packed(_pack(w, 8, variant, 64), to_bits, variant)
+    wq = bp.shift_requantize(w, 8, to_bits, variant)
+    if variant == "booth":
+        assert int(wq.max()) == 1 << (to_bits - 1)  # one past the signed max
+    single, gated, oracle = _three_ways(a, packed, 8, variant)
+    np.testing.assert_array_equal(single, oracle)
+    np.testing.assert_array_equal(single, gated)
+    np.testing.assert_array_equal(single, a.astype(jnp.int32) @ wq)
+
+
+@pytest.mark.parametrize("variant", ["booth", "sbmwc"])
+@pytest.mark.parametrize("to_bits", [8, 6, 4])
+def test_single_pass_compacted_cache(variant, to_bits, rng):
+    """A compacted cache (all-zero planes dropped, kept planes keep their
+    weights) and its truncated prefixes: the slots follow the weights."""
+    a, _ = _operands(rng, 5, 130, 9, 8, 8)
+    w = jnp.asarray(rng.integers(-8, 8, (130, 9)), jnp.int32) * 4  # planes 0, 1 empty
+    packed = bp.compact_packed(_pack(w, 8, variant, 64))
+    assert packed.n_planes < 8
+    packed = bp.truncate_packed(packed, to_bits, variant, from_bits=8)
+    single, gated, oracle = _three_ways(a, packed, 8, variant)
+    np.testing.assert_array_equal(single, oracle)
+    np.testing.assert_array_equal(single, gated)
+    np.testing.assert_array_equal(
+        single, a.astype(jnp.int32) @ bp.shift_requantize(w, 8, to_bits, variant))
+
+
+@pytest.mark.parametrize("variant", ["booth", "sbmwc"])
+def test_single_pass_extreme_weights(variant, rng):
+    """Weights at -128 (SBMwC: the MSB plane alone, its negative weight
+    sign-extended over the top slots) and at 127, next to small ones."""
+    a, w = _operands(rng, 8, 96, 16, 8, 8)
+    w = w.at[::3, :].set(-128).at[1::7, ::2].set(127).at[2, :].set(-1)
+    single, gated, oracle = _three_ways(a, _pack(w, 8, variant, 32), 8, variant)
+    np.testing.assert_array_equal(single, oracle)
+    np.testing.assert_array_equal(single, gated)
+    np.testing.assert_array_equal(single, a.astype(jnp.int32) @ w)
+
+
+@pytest.mark.parametrize("m,k,n", [(5, 96, 9), (8, 256, 16)])
+def test_single_pass_booth_plus_128(m, k, n, rng):
+    """Booth's closed range reaches +2^7 at 8 bits (a 16-bit store's 8-bit
+    prefix rounds up to it): the int8 rebuild reads -128 there, and the
+    correcting pass adds 256 * a only in the K blocks that hold one."""
+    a, w = _operands(rng, m, k, n, 8, 8)
+    w = w.at[3, 2].set(128).at[k - 1, n - 1].set(128).at[40, :].set(128)
+    single, gated, oracle = _three_ways(a, _pack(w, 8, "booth", 32), 8, "booth")
+    np.testing.assert_array_equal(single, oracle)
+    np.testing.assert_array_equal(single, gated)
+    np.testing.assert_array_equal(single, a.astype(jnp.int32) @ w)
+
+
+@pytest.mark.parametrize("variant", ["booth", "sbmwc"])
+@pytest.mark.parametrize("a_bits", [4, 6])
+def test_single_pass_activations_wrap(variant, a_bits, rng):
+    """Activations outside the a_bits range wrap as the sliced planes do:
+    the sign extension of their low a_bits bits, as in the plane-pair
+    loop. (The jnp oracle's Booth decomposition reads exactly +2^(a-1) as
+    itself, by its closed range; the in-range cases compare it.)"""
+    a = jnp.asarray(rng.integers(-128, 128, (7, 80)), jnp.int8)
+    _, w = _operands(rng, 7, 80, 10, 8, 8)
+    packed = _pack(w, 8, variant, 32)
+    single = ops.fused_linear(a, packed, None, a_bits=a_bits, variant=variant,
+                              backend="interpret", bm=8, bn=8)
+    gated = ops.fused_linear(a, packed, None, a_bits=a_bits, variant=variant,
+                             backend="interpret", bm=8, bn=8, gate=True)
+    s = 32 - a_bits
+    wrapped = (a.astype(jnp.int32) << s) >> s
+    np.testing.assert_array_equal(single, gated)
+    np.testing.assert_array_equal(single, wrapped @ w)
+
+
+@pytest.mark.parametrize("variant", ["booth", "sbmwc"])
+@pytest.mark.parametrize("raw", [True, False], ids=["raw_int32", "epilogue"])
+def test_single_pass_output_modes(variant, raw, rng):
+    """The raw int32 mode (the TP row role's, has_epilogue=False) and the
+    in-kernel epilogue both sit on the one pass."""
+    a, w = _operands(rng, 5, 100, 12, 8, 8)
+    ep = None if raw else ops.Epilogue(
+        jnp.asarray(rng.uniform(0.01, 0.1, (5, 1)), jnp.float32),
+        jnp.asarray(rng.uniform(0.01, 0.1, (1, 12)), jnp.float32),
+        out_dtype=jnp.float32,
+    )
+    single, gated, oracle = _three_ways(a, _pack(w, 8, variant, 32), 8, variant,
+                                        epilogue=ep)
+    if raw:
+        assert single.dtype == jnp.int32
+        np.testing.assert_array_equal(single, oracle)
+        np.testing.assert_array_equal(single, gated)
+    else:
+        want = ops.apply_epilogue(a.astype(jnp.int32) @ w, ep)
+        np.testing.assert_allclose(single, want, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(single, gated, rtol=1e-6, atol=1e-6)
+
+
+def test_single_pass_refuses_operands_without_int8_form(rng):
+    """Operands with no int8 form (unsigned 8-bit activations, a plane
+    weighted 2^8) have no single pass; the gated loop still takes them."""
+    from repro.kernels.plane_mm_fused import single_pass_fits, weight_slots
+
+    assert weight_slots(bp.plane_weights(8, "booth"), True) == tuple(range(8))
+    assert weight_slots(bp.plane_weights(4, "sbmwc"), False) == (0, 1, 2, 3, 3, 3, 3, 3)
+    assert weight_slots(bp.plane_weights(8, "unsigned"), False) is None
+    assert weight_slots(bp.plane_weights(9, "booth"), True) is None
+    assert not single_pass_fits(bp.plane_weights(8, "sbmwc"), False, 8, "unsigned")
+    a = jnp.asarray(rng.integers(0, 200, (4, 64)), jnp.int8)
+    w = jnp.asarray(rng.integers(-8, 8, (64, 8)), jnp.int32)
+    packed = _pack(w, 4, "sbmwc", 32)
+    with pytest.raises(ValueError, match="single-pass"):
+        ops.fused_linear(a, packed, None, a_bits=8, variant="unsigned",
+                         backend="interpret", bm=8, bn=8)
+    got = ops.fused_linear(a, packed, None, a_bits=8, variant="unsigned",
+                           backend="interpret", bm=8, bn=8, gate=True)
+    np.testing.assert_array_equal(got, (a.astype(jnp.int32) & 255) @ w)
+
+
+def test_single_pass_booth_plus_128_through_plan(rng):
+    """A plan reaches Booth's +2^7: a 16-bit store served at the 8-bit tier
+    (prefix truncation rounds 32767 up to 128) takes the fused route, and
+    its output equals the jnp route's."""
+    from repro.core import plan as plan_mod
+
+    a = jnp.asarray(rng.integers(-128, 128, (4, 96)), jnp.int8)
+    w = jnp.asarray(rng.integers(-32768, 32768, (96, 8)), jnp.int32).at[5, :].set(32767)
+    wp = bp.make_weight_planes(w, w_bits=16, variant="booth", level="bitplane", block=32)
+    assert int(bp.shift_requantize(w, 16, 8, "booth").max()) == 128
+    ep = ops.Epilogue(jnp.full((4, 1), 0.5, jnp.float32), jnp.full((1, 8), 0.25, jnp.float32),
+                      out_dtype=jnp.float32)
+    kw = dict(a_bits=8, w_bits=8, w_in_bits=16, variant="booth", level="bitplane",
+              has_epilogue=True, w_planes=wp, registry=plan_mod.PlanRegistry())
+    fused = plan_mod.plan_for_operands((4, 96, 8), backend="interpret", bm=8, bn=8, **kw)
+    assert fused.kernel == "fused_cached"
+    oracle = plan_mod.plan_for_operands((4, 96, 8), backend="jnp", **kw)
+    np.testing.assert_array_equal(fused(a, w_planes=wp, epilogue=ep),
+                                  oracle(a, w_planes=wp, epilogue=ep))

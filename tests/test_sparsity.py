@@ -289,8 +289,33 @@ def test_sparsity_stats_totals_match_reference(rng):
                                       backend="jnp", sparsity="gate")
     assert bare.sparsity_stats() == {
         "mode": "gate", "kernel": bare.kernel, "gated": False,
-        "planes_dense": 8, "a_planes": 8,
+        "planes_dense": 8, "a_planes": 8, "mxu_passes_per_tile": 64,
     }
+
+
+@pytest.mark.parametrize("sparsity", ["off", "gate", "compact"])
+@pytest.mark.parametrize("tier", [8, 4])
+def test_mxu_passes_per_tile_names_the_path(sparsity, tier, rng):
+    """The fused route makes one int8 MXU pass a tile; gated, it runs the
+    plane-pair loop, a_bits x (kept, truncated) planes passes a tile."""
+    v = _narrow_weights(rng, 130, 9)
+    wp = bp.make_weight_planes(v, w_bits=8, variant="booth", level="bitplane",
+                               block=64)
+    if sparsity == "compact":
+        wp = bp.compact_weight_planes(wp)
+    plan = plan_mod.plan_for_operands(
+        (5, 130, 9), a_bits=8, w_bits=8, variant="booth", level="bitplane",
+        backend="interpret", w_planes=wp, sparsity=sparsity, has_epilogue=True,
+        registry=plan_mod.PlanRegistry(),
+    ).with_precision(w_bits=tier)
+    assert plan.kernel == "fused_cached"
+    assert plan.gate == (sparsity != "off")
+    stats = plan.sparsity_stats(wp)
+    kept = bp.truncate_weight_planes(wp, tier).packed.n_planes
+    assert stats["mxu_passes_per_tile"] == (1 if sparsity == "off" else 8 * kept)
+    # without the cache the count is the dense one
+    assert plan.sparsity_stats()["mxu_passes_per_tile"] == (
+        1 if sparsity == "off" else 8 * tier)
 
 
 # -- layer / serving integration ---------------------------------------------
@@ -328,6 +353,24 @@ def test_auto_tiles_bn():
     assert ops.auto_tiles(4, 700, None, None, n=200) == (8, 256, 512)
     assert ops.auto_tiles(4, 700, None, None, n=4096) == (8, 256, 512)
     assert ops.auto_tiles(4, 700, None, None, n=4096, bn=512) == (8, 512, 512)
+
+
+def test_fused_step_from_shape():
+    """The single-pass kernel's grid step: the widest lane-multiple bn up to
+    1024 that divides N (in lanes), then up to 4 pack blocks a K step, at
+    most 2048 weight columns a step, dividing K's blocks."""
+    assert [ops.fused_bn(n) for n in (96, 4096, 11008, 32000, 49155, 12800)] == [
+        128, 1024, 256, 640, 896, 640]
+    assert ops.fused_k_blocks(1024, 8) == 2  # LLaMA q/o: K 4096
+    assert ops.fused_k_blocks(256, 8) == 4  # gate/up: N 11008
+    assert ops.fused_k_blocks(1024, 22) == 2  # down: K 11008
+    assert ops.fused_k_blocks(640, 8) == 2  # lm_head: N 32000
+    assert ops.fused_k_blocks(640, 25) == 1
+    # a fused plan takes the wide step; gated, the plane-pair loop keeps 256
+    kw = dict(a_bits=8, w_bits=8, variant="booth", level="bitplane",
+              backend="interpret", has_epilogue=True, registry=plan_mod.PlanRegistry())
+    assert plan_mod.plan_for_operands((32, 4096, 4096), **kw).bn == 1024
+    assert plan_mod.plan_for_operands((32, 4096, 4096), sparsity="gate", **kw).bn == 256
 
 
 def test_fused_decode_auto_bn(rng):
