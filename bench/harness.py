@@ -3,8 +3,9 @@
 ``python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>``
 
 Everything the run needs is found by name from ``BENCHMARK.json``: the
-cell's configuration (``bench/configs/<config>.json``), its traffic
-(``bench/traffic/<traffic>.json``), the limits of its check
+cell's configuration (``bench/configs/<config>.json``) and, by its
+``model_type``, the architecture (``bench/arch/<model_type>.py``), its
+traffic (``bench/traffic/<traffic>.json``), the limits of its check
 (``bench/limits/<cell>.json``) and one reader per per-layer metric
 (``bench/metrics/<metric>.py``).
 
@@ -32,7 +33,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import shutil
 import sys
@@ -42,7 +42,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import seam, traffic, weights, work
+from bench import arch, seam, traffic, weights, work
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -102,11 +102,7 @@ def load_cell(name: str, bench_file: Path = ROOT / "BENCHMARK.json") -> Cell:
 
 
 def load_reader(metric: str):
-    path = BENCH / "metrics" / f"{metric}.py"
-    sp = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
-    return mod.read
+    return arch.load_path(BENCH / "metrics" / f"{metric}.py").read
 
 
 def peaks_for(kind: str) -> dict:
@@ -118,22 +114,7 @@ def peaks_for(kind: str) -> dict:
 
 def program_config(cfg: dict):
     """The program's ModelConfig for a configuration file."""
-    from repro.models.config import ModelConfig
-
-    return ModelConfig(
-        name=cfg["name"],
-        family="dense",
-        n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"],
-        vocab_size=cfg["vocab_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        act="swiglu",
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-    )
+    return arch.for_config(cfg).program_config(cfg)
 
 
 def use_compile_cache() -> str:
@@ -232,7 +213,7 @@ def build_engine(cell: Cell, seed: int):
     t = time.perf_counter()
     template = jax.eval_shape(lambda: init_params(mcfg, jax.random.PRNGKey(0)))
     params = weights.to_program_tree(jax.block_until_ready(weights.canonical(cfg, seed)),
-                                     template)
+                                     template, cfg)
     t_weights = time.perf_counter() - t
     page = srv["page_size"]
     max_len = -(-traffic.max_extent(tr) // page) * page

@@ -4,15 +4,18 @@ tier's packed weight prefix plus activations and outputs at the HBM
 peak, whichever is longer), summed, over the kernel's device time in the
 trace. Each call's M, K, N and plane count are read from its operand
 shapes in the trace (``%fused_plane_linear.N = bf16[M,N] custom-call(
-pair weights, s8[M,K], s32[P,K/32,N] magnitudes, ...)``). The work of a
-linear is that of its product, whatever number of plane passes computes
-it. Moves ``out_tok_s``."""
+pair weights, s8[M,K], s32[P,K/32,N] magnitudes, ...)``); a kernel that
+reads a layer's words in place from the stack, ``s32[L,P,K/32,N]``, with
+an ``s32[1]`` layer index before the pair weights, is charged one layer's
+work. The work of a linear is that of its product, whatever number of
+plane passes computes it. Moves ``out_tok_s``."""
 
 import re
 
 KERNEL = re.compile(
     r"^%fused_plane_linear(?:\.\d+)? = \w+\[(\d+),(\d+)\]\S* custom-call\("
-    r"\w+\[\d+\]\S* %\S+, s8\[(\d+),(\d+)\]\S* %\S+, s32\[(\d+),\d+,\d+\]"
+    r"(?:s32\[1\]\S* %\S+, )?\w+\[\d+\]\S* %\S+, s8\[(\d+),(\d+)\]\S* %\S+, "
+    r"s32\[(?:\d+,)?(\d+),\d+,\d+\]"
 )
 
 

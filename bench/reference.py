@@ -1,11 +1,13 @@
 """Plain reference of the served model, independent of the program.
 
-A dense GQA decoder with RoPE (split-half rotation), RMSNorm and a SwiGLU
-MLP, as the configuration file states it, with every linear at the
-precision the cell serves, computed in float32 with every matmul at
-``Precision.HIGHEST``. No kernel, cache or batching: one whole sequence
-at a time, the layers scanned one by one with each layer's bf16 weights
-widened to f32 inside the scan, so only one layer is ever held in f32.
+The model is the configuration's architecture (``bench/arch/<model_type>.py``
+gives its ``logits``), as the configuration file states it, with every
+linear at the precision the cell serves, computed in float32 with every
+matmul at ``Precision.HIGHEST``. No kernel, cache or batching: one whole
+sequence at a time, the layers scanned one by one with each layer's bf16
+weights widened to f32 inside the scan, so only one layer is ever held in
+f32. This module holds what every architecture shares: the tier's
+quantizers, the quantized linear, RMSNorm and RoPE.
 
 The precision of a tier of ``bits`` (the bit-plane storage contract):
 each weight column is quantized symmetrically to the stored 8 bits
@@ -22,10 +24,13 @@ It imports nothing of the program. Weights come from ``bench.weights``.
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from bench import arch
 
 HIGHEST = lax.Precision.HIGHEST
 
@@ -72,37 +77,16 @@ def _rope(x, theta):
 def logits(cfg: dict, W: dict, tokens, bits: int):
     """(S, vocab_size) float32 logits of every position of ``tokens`` at
     a tier of ``bits``."""
-    hq, hkv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
-    s = tokens.shape[0]
-    x = W["embed"][tokens].astype(jnp.float32)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    layers = {k: W[k] for k in ("attn_norm", "wq", "wk", "wv", "wo", "mlp_norm",
-                                "w_gate", "w_up", "w_down")}
+    return arch.for_config(cfg).logits(cfg, W, tokens, bits)
 
-    def block(x, lw):
-        h = _rms(x, lw["attn_norm"], eps)
-        q = _rope(_linear(h, lw["wq"], bits).reshape(s, hq, hd), theta)
-        k = _rope(_linear(h, lw["wk"], bits).reshape(s, hkv, hd), theta)
-        v = _linear(h, lw["wv"], bits).reshape(s, hkv, hd)
-        k = jnp.repeat(k, hq // hkv, axis=1)  # q-head h reads kv-head h // group
-        v = jnp.repeat(v, hq // hkv, axis=1)
-        sc = jnp.einsum("qhd,khd->hqk", q, k, precision=HIGHEST) * hd**-0.5
-        p = jax.nn.softmax(jnp.where(causal[None], sc, -jnp.inf), axis=-1)
-        o = jnp.einsum("hqk,khd->qhd", p, v, precision=HIGHEST).reshape(s, hq * hd)
-        x = x + _linear(o, lw["wo"], bits)
-        h = _rms(x, lw["mlp_norm"], eps)
-        g = jax.nn.silu(_linear(h, lw["w_gate"], bits)) * _linear(h, lw["w_up"], bits)
-        return x + _linear(g, lw["w_down"], bits), None
 
-    x, _ = lax.scan(block, x, layers)
-    x = _rms(x, W["final_norm"], eps)
-    return _linear(x, W["head"], bits)[:, : cfg["vocab_size"]]
+#: configuration keys that describe the run, not the model
+NOT_MODEL = ("serving", "assumed", "published", "deployment")
 
 
 @functools.lru_cache(maxsize=None)
-def _scorer(cfg_items: tuple, bits: int):
-    cfg = dict(cfg_items)
+def _scorer(cfg_json: str, bits: int):
+    cfg = json.loads(cfg_json)
 
     @jax.jit
     def score(W, tokens, targets):
@@ -119,6 +103,5 @@ def score(cfg: dict, W: dict, tokens, targets, bits: int):
     target set) and the argmax. Positions past the real sequence are
     garbage the caller does not read; causality keeps them out of the
     rest."""
-    keys = ("num_attention_heads", "num_key_value_heads", "head_dim", "rms_norm_eps",
-            "rope_theta", "vocab_size")
-    return _scorer(tuple((k, cfg[k]) for k in keys), bits)(W, tokens, targets)
+    model = {k: v for k, v in cfg.items() if k not in NOT_MODEL}
+    return _scorer(json.dumps(model, sort_keys=True), bits)(W, tokens, targets)

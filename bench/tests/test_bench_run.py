@@ -121,7 +121,7 @@ def test_the_reference_agrees_with_the_program_in_float32():
     W = weights.canonical(cfg, tiny.SEED)
     tmpl = jax.eval_shape(lambda: init_params(mcfg, jax.random.PRNGKey(0)))
     P = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
-                               weights.to_program_tree(W, tmpl))
+                               weights.to_program_tree(W, tmpl, cfg))
     toks = jnp.arange(40, dtype=jnp.int32) * 7 % cfg["vocab_size"]
     with jax.default_matmul_precision("highest"):
         got, _, _ = forward(mcfg, P, {"tokens": toks[None]})

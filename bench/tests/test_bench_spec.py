@@ -91,3 +91,12 @@ def test_peaks_by_device_kind():
     assert harness.peaks_for("TPU v5 lite")["int8_ops_per_s"] == 393e12
     with pytest.raises(harness.Refused):
         harness.peaks_for("TPU v9 imaginary")
+
+
+@pytest.mark.parametrize("cfg", SPEC["configs"], ids=lambda c: c["name"])
+def test_every_configuration_has_an_architecture(cfg):
+    from bench import arch
+
+    data = json.load(open(harness.ROOT / cfg["file"]))
+    assert data["model_type"] in arch.names()
+    assert arch.for_config(data).program_config(data).name == cfg["name"]

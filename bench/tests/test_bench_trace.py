@@ -88,3 +88,49 @@ def test_a_trace_without_the_window_or_device_is_refused():
         tracefile.reduce(p)
     with pytest.raises(RuntimeError, match="device op"):
         tracefile.reduce(Profile([profile().planes[0]]))
+
+
+ROOFLINE_OPS = {
+    # the words of one layer, sliced out of the stack before the call
+    "layer": "%fused_plane_linear.12 = bf16[32,4096]{1,0:T(8,128)(2,1)} custom-call("
+             "s32[64]{0:T(128)} %p, s8[32,4096]{1,0:T(8,128)(4,1)} %x, "
+             "s32[8,128,4096]{2,1,0:T(8,128)} %mag, s32[8,128,4096]{2,1,0:T(8,128)} %sign, "
+             "f32[32,1]{1,0} %as, f32[1,4096]{1,0} %ws), custom_call_target=\"tpu_custom_call\"",
+    # the whole stack of 8 layers and the layer's index, read in place
+    "stack": "%fused_plane_linear = bf16[32,4096]{1,0:T(8,128)(2,1)} custom-call("
+             "s32[1]{0} %layer, s32[64]{0:T(128)} %p, s8[32,4096]{1,0:T(8,128)(4,1)} %x, "
+             "s32[8,8,128,4096]{3,2,1,0:T(8,128)} %mag, s32[8,8,128,4096]{3,2,1,0} %sign, "
+             "f32[32,1]{1,0} %as, f32[1,4096]{1,0} %ws), custom_call_target=\"tpu_custom_call\"",
+}
+
+
+@pytest.mark.parametrize("form", sorted(ROOFLINE_OPS))
+def test_the_roofline_reads_one_layer_of_words_in_either_form(form):
+    """M=32, K=4096, N=4096 at 8 planes, 100 us a call: one layer's work."""
+    import types
+
+    from bench import harness, work
+
+    peaks = {"int8_ops_per_s": 393e12, "hbm_bytes_per_s": 819e9}
+    calls = [tracefile.Event(ROOFLINE_OPS[form], t, t + 100_000) for t in (0, 200_000)]
+    trace = types.SimpleNamespace(ops=lambda pattern: calls)
+    ctx = types.SimpleNamespace(trace=trace, work=work, peaks=peaks)
+    got = harness.load_reader("plane_mm_roofline")(ctx)
+    assert got == 100.0 * 2 * work.least_seconds(32, 4096, 4096, 8, peaks) / 200e-6
+
+
+@pytest.mark.parametrize("old,new", [
+    ("s8[32,4096]", "bf16[32,4096]"),                  # activations that are not int8
+    ("s32[64]{0:T(128)} %p, ", ""),                    # no pair-weight vector
+], ids=["bf16-activations", "no-pair-weights"])
+def test_the_roofline_refuses_a_call_it_cannot_read(old, new):
+    import types
+
+    from bench import harness, work
+
+    name = ROOFLINE_OPS["layer"].replace(old, new)
+    assert name != ROOFLINE_OPS["layer"]
+    trace = types.SimpleNamespace(ops=lambda pattern: [tracefile.Event(name, 0, 100)])
+    ctx = types.SimpleNamespace(trace=trace, work=work, peaks={})
+    with pytest.raises(RuntimeError, match="unparsed fused_plane_linear"):
+        harness.load_reader("plane_mm_roofline")(ctx)

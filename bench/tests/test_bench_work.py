@@ -5,9 +5,9 @@ import pytest
 from bench import work
 
 PEAKS = {"int8_ops_per_s": 393e12, "hbm_bytes_per_s": 819e9}
-GRANITE = {"hidden_size": 4096, "num_hidden_layers": 8, "num_attention_heads": 32,
-           "num_key_value_heads": 8, "head_dim": 128, "intermediate_size": 12800,
-           "vocab_size": 49155}
+GRANITE = {"model_type": "llama", "hidden_size": 4096, "num_hidden_layers": 8,
+           "num_attention_heads": 32, "num_key_value_heads": 8, "head_dim": 128,
+           "intermediate_size": 12800, "vocab_size": 49155}
 
 
 @pytest.mark.parametrize("m,k,n", [(1, 4096, 4096), (32, 4096, 12800), (64, 12800, 4096)])

@@ -1,9 +1,11 @@
 """Seeded model weights, made by the benchmark and not by the program.
 
 One jitted call draws every weight on the device from ``--seed`` in the
-bench's own layout (``canonical``). The program is handed the same arrays
-rearranged into its parameter tree (``to_program_tree``); the reference
-reads the canonical layout directly, so it takes nothing the program made.
+bench's own layout (``canonical``: the names and shapes that the
+configuration's architecture, ``bench/arch/<model_type>.py``, gives). The
+program is handed the same arrays rearranged into its parameter tree
+(``to_program_tree``); the reference reads the canonical layout directly,
+so it takes nothing the program made.
 
 Distributions: linear weights N(0, 1/d_in), the embedding N(0, 1/d_model),
 RMSNorm scales 1 + 0.1·N(0, 1) (random, so a norm that is not applied
@@ -17,6 +19,8 @@ import functools
 
 import numpy as np
 
+from bench import arch
+
 
 def seed_key(seed: int):
     """A threefry key from any whole-number seed (wider than 32 bits too)."""
@@ -28,24 +32,8 @@ def seed_key(seed: int):
 
 
 def shapes(cfg: dict) -> dict:
-    """Canonical weight shapes of a dense GQA SwiGLU decoder config."""
-    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
-    hq, hkv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    ff, vp = cfg["intermediate_size"], padded_vocab(cfg)
-    return {
-        "embed": (vp, d),
-        "attn_norm": (L, d),
-        "wq": (L, d, hq * hd),
-        "wk": (L, d, hkv * hd),
-        "wv": (L, d, hkv * hd),
-        "wo": (L, hq * hd, d),
-        "mlp_norm": (L, d),
-        "w_gate": (L, d, ff),
-        "w_up": (L, d, ff),
-        "w_down": (L, ff, d),
-        "final_norm": (d,),
-        "head": (d, vp),
-    }
+    """Canonical weight name -> shape of ``cfg``'s architecture."""
+    return arch.for_config(cfg).shapes(cfg)
 
 
 def padded_vocab(cfg: dict) -> int:
@@ -82,43 +70,24 @@ def canonical(cfg: dict, seed: int) -> dict:
     return _generator(tuple(sorted(shapes(cfg).items())))(seed_key(seed))
 
 
-# program tree path -> canonical name (stacked layers under periods/b0_dense)
-_BLOCK = {
-    ("attn_norm", "scale"): "attn_norm",
-    ("attn", "q_proj", "w"): "wq",
-    ("attn", "k_proj", "w"): "wk",
-    ("attn", "v_proj", "w"): "wv",
-    ("attn", "o_proj", "w"): "wo",
-    ("mlp_norm", "scale"): "mlp_norm",
-    ("mlp", "gate_proj", "w"): "w_gate",
-    ("mlp", "up_proj", "w"): "w_up",
-    ("mlp", "down_proj", "w"): "w_down",
-}
-_TOP = {
-    ("embed", "embedding"): "embed",
-    ("final_norm", "scale"): "final_norm",
-    ("lm_head", "head", "w"): "head",
-}
-
-
 def _path_keys(path) -> tuple:
     return tuple(getattr(p, "key", getattr(p, "idx", p)) for p in path)
 
 
-def to_program_tree(canon: dict, template):
+def to_program_tree(canon: dict, template, cfg: dict):
     """Lay ``canon`` out as the program's parameter tree ``template`` (a
-    tree of ShapeDtypeStructs, e.g. ``jax.eval_shape`` of its init).
+    tree of ShapeDtypeStructs, e.g. ``jax.eval_shape`` of its init), each
+    leaf named by ``cfg``'s architecture (``leaf_name``).
 
-    Raises if the program's layout has a leaf this mapping does not know,
-    or a shape or dtype differs: the seam to the program has moved."""
+    Raises if a leaf of the program's layout has no name, or a shape or
+    dtype differs: the seam to the program has moved."""
     import jax
+
+    name_of = arch.for_config(cfg).leaf_name
 
     def pick(path, leaf):
         keys = _path_keys(path)
-        if keys[:2] == ("periods", "b0_dense"):
-            name = _BLOCK.get(keys[2:])
-        else:
-            name = _TOP.get(keys)
+        name = name_of(keys)
         if name is None:
             raise RuntimeError(f"program parameter {'/'.join(map(str, keys))} has no bench weight")
         arr = canon[name]

@@ -1,14 +1,19 @@
 """Work the model asks for, computed from shapes alone.
 
-The yardstick of ``plane_mm_roofline`` and ``mfu``. The work of a linear
-is that of the matrix product it computes, whatever computes it: a
-bit-serial route that makes ``a_bits × w_bits`` int8 passes and a route
-that makes one are charged the same operations. Bytes are what the tier
-has to read and write at the least: the tier's prefix of the stored
-weight planes, the int8 activations, the bf16 output and the f32 scales.
+The yardstick of ``plane_mm_roofline`` and ``mfu``. Which linears a
+token passes through, and the operations of a decoded token or a prompt,
+are the configuration's architecture's (``bench/arch/<model_type>.py``);
+what a linear costs is this module's. The work of a linear is that of
+the matrix product it computes, whatever computes it: a bit-serial route
+that makes ``a_bits × w_bits`` int8 passes and a route that makes one
+are charged the same operations. Bytes are what the tier has to read and
+write at the least: the tier's prefix of the stored weight planes, the
+int8 activations, the bf16 output and the f32 scales.
 """
 
 from __future__ import annotations
+
+from bench import arch
 
 #: stored bits per weight and plane: a Booth signed digit is kept as a
 #: magnitude bit plus a sign bit (two packed words per 32 weights)
@@ -17,15 +22,7 @@ BOOTH_BITS_PER_PLANE = 2
 
 def linears(cfg: dict) -> list[tuple[str, int, int]]:
     """(name, K, N) of every linear one token passes through, in order."""
-    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
-    hq, hkv, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
-    ff = cfg["intermediate_size"]
-    block = [
-        ("q_proj", d, hq * hd), ("k_proj", d, hkv * hd), ("v_proj", d, hkv * hd),
-        ("o_proj", hq * hd, d), ("gate_proj", d, ff), ("up_proj", d, ff),
-        ("down_proj", ff, d),
-    ]
-    return block * L + [("lm_head", d, cfg["vocab_size"])]
+    return arch.for_config(cfg).linears(cfg)
 
 
 def linear_ops(m: int, k: int, n: int) -> int:
@@ -48,28 +45,11 @@ def least_seconds(m: int, k: int, n: int, w_bits: int, peaks: dict) -> float:
     )
 
 
-def _per_token(cfg: dict) -> tuple[int, int, int]:
-    """(ops of the blocks' linears, ops of the lm_head, attention ops per
-    key) for one token."""
-    lin = linears(cfg)
-    blocks = sum(linear_ops(1, k, n) for name, k, n in lin if name != "lm_head")
-    head = sum(linear_ops(1, k, n) for name, k, n in lin if name == "lm_head")
-    attn = 4 * cfg["num_hidden_layers"] * cfg["num_attention_heads"] * cfg["head_dim"]
-    return blocks, head, attn
-
-
 def decode_ops(cfg: dict, position: int) -> int:
-    """Model operations of decoding the token at 0-based ``position``:
-    every weight touched once (2 ops each), the lm_head included, plus
-    attention over the ``position + 1`` keys it sees (scores and
-    weighted sum)."""
-    blocks, head, attn = _per_token(cfg)
-    return blocks + head + attn * (position + 1)
+    """Model operations of decoding the token at 0-based ``position``."""
+    return arch.for_config(cfg).decode_ops(cfg, position)
 
 
 def prompt_ops(cfg: dict, length: int) -> int:
-    """Model operations of prefilling a ``length``-token prompt: every
-    position through the blocks with causal attention, the lm_head once
-    (only the last position's logits are computed)."""
-    blocks, head, attn = _per_token(cfg)
-    return length * blocks + head + attn * length * (length + 1) // 2
+    """Model operations of prefilling a ``length``-token prompt."""
+    return arch.for_config(cfg).prompt_ops(cfg, length)
